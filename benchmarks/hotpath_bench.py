@@ -589,6 +589,8 @@ if __name__ == "__main__":
     ap.add_argument("--metrics-out", metavar="PATH", default=None,
                     help="write per-shape medians as JSONL telemetry")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.check_baseline:
         if args.pipeline:
             raise SystemExit(check_pipeline(args.check_baseline))

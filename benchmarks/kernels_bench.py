@@ -145,4 +145,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="CI-sized smoke (one shape, one skew)")
-    run(quick=ap.parse_args().quick)
+    args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    run(quick=args.quick)

@@ -1,5 +1,5 @@
 """Mesh-real collective benchmark: managed vs plain lookup over the
-`shard_map` psum data path (DESIGN.md §10), on an 8-device host mesh.
+`shard_map` psum data path (DESIGN.md §10), on a multi-device mesh.
 
 This is the acceptance measurement for the collective-backend layer: with
 the table vocab-sharded over a real ``("model",)`` mesh, the managed path
@@ -24,8 +24,10 @@ the psum while the plain vocab-parallel baseline moves every token's row
     isolates exactly what the PR changed — collective layout and memory
     traffic.
 
-Needs a multi-device host; when launched on a single-device one (e.g.
-from ``benchmarks.run``) it re-execs itself in a subprocess with
+On an accelerator host the mesh spans the devices present, in this one
+process (a chip belongs to the process that touched JAX first).  On the
+CPU backend it needs 8 virtual devices; a CPU process with fewer (e.g.
+launched from ``benchmarks.run``) re-execs itself in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — the flag only
 takes effect before jax initializes.  Writes ``BENCH_mesh.json`` at the
 repo root next to the other BENCH_* trajectories.
@@ -59,7 +61,7 @@ import numpy as np
 _OUT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                     "BENCH_mesh.json")
 
-N_DEV = 8
+N_DEV = 8                # virtual devices of a CPU host-platform run
 V, D = 32768, 256
 B, K = 16, 256           # T = 4096 tokens per batch
 C = 4096                 # replica-cache capacity (holds the Zipf head)
@@ -142,6 +144,21 @@ def _reexec(quick: bool, trace_path=None, metrics_path=None) -> List[str]:
                        os.path.abspath(__file__)), ".."))
     with open(_OUT) as f:
         return _rows(json.load(f))
+
+
+def _mesh_size() -> int:
+    """Devices for the bench mesh, or 0 when this process must re-exec
+    with ``N_DEV`` virtual CPU devices.  Only the CPU backend can be given
+    virtual devices and shared with a child process; an accelerator host
+    runs on the devices it has, in this process.  ``JAX_PLATFORMS=cpu``
+    settles the platform before JAX initializes; otherwise the backend
+    JAX picks decides."""
+    cpu = os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+    import jax
+    devices = jax.devices()
+    if cpu or devices[0].platform == "cpu":
+        return N_DEV if len(devices) >= N_DEV else 0
+    return len(devices)
 
 
 def _bucket(n, floor=64):
@@ -235,9 +252,9 @@ def _paired_step_medians(legacy, fused, table, accum, iters: int):
     return float(np.median(tl) * 1e6), float(np.median(tf) * 1e6)
 
 
-def _fused_arm(quick: bool, tracer=None, bus=None):
+def _fused_arm(quick: bool, n_dev: int, tracer=None, bus=None):
     """The ISSUE 6 acceptance measurement: routed fused step vs the PR-4
-    replica, per Zipf skew, on the 8-device mesh."""
+    replica, per Zipf skew, on the ``n_dev``-device mesh."""
     import jax.numpy as jnp
 
     from repro.data.pipeline import SyntheticCorpus
@@ -247,7 +264,7 @@ def _fused_arm(quick: bool, tracer=None, bus=None):
     from repro.pm.embedding import make_state, probe_host
 
     tr = make_tracer(False, tracer=tracer)
-    backend = MeshBackend(make_model_mesh(N_DEV))
+    backend = MeshBackend(make_model_mesh(n_dev))
     rng = np.random.default_rng(0)
     table = backend.place_table(
         jnp.asarray(rng.normal(size=(V, D)), jnp.float32))
@@ -283,7 +300,7 @@ def _fused_arm(quick: bool, tracer=None, bus=None):
     return entries
 
 
-def _pipeline_arm(quick: bool) -> dict:
+def _pipeline_arm(quick: bool, n_dev: int) -> dict:
     """§15 pipeline arm (DESIGN.md §15): the fused routed step under
     refresh-every-round replica sync, synchronous (full C-row replicated
     psum re-gather + per-round block) vs pipelined (routed delta
@@ -302,7 +319,7 @@ def _pipeline_arm(quick: bool) -> dict:
 
     from .common import paired_pooled_ratio
 
-    backend = MeshBackend(make_model_mesh(N_DEV))
+    backend = MeshBackend(make_model_mesh(n_dev))
     rng = np.random.default_rng(0)
     table0 = np.asarray(rng.normal(size=(V, D)), np.float32)
     accum0 = np.full((V, D), 0.1, np.float32)
@@ -387,7 +404,8 @@ def _geomean(vals):
     return float(np.exp(np.mean(np.log(list(vals)))))
 
 
-def _run_local(quick: bool, trace_path=None, metrics_path=None):
+def _run_local(quick: bool, n_dev: int, trace_path=None,
+               metrics_path=None):
     import jax
     import jax.numpy as jnp
 
@@ -404,7 +422,7 @@ def _run_local(quick: bool, trace_path=None, metrics_path=None):
     tracer = make_tracer(bool(trace_path))
     bus = Telemetry() if metrics_path else None
     t_start = time.time()
-    backend = MeshBackend(make_model_mesh(N_DEV))
+    backend = MeshBackend(make_model_mesh(n_dev))
     rng = np.random.default_rng(0)
     table = backend.place_table(
         jnp.asarray(rng.normal(size=(V, D)), jnp.float32))
@@ -472,11 +490,11 @@ def _run_local(quick: bool, trace_path=None, metrics_path=None):
             "train_fwd_bwd_plain_us": round(train_p_us, 1),
         })
 
-    fused_entries = _fused_arm(quick, tracer=tracer, bus=bus)
-    pipeline = _pipeline_arm(quick)
+    fused_entries = _fused_arm(quick, n_dev, tracer=tracer, bus=bus)
+    pipeline = _pipeline_arm(quick, n_dev)
     summary = {
         "config": {"vocab": V, "dim": D, "tokens_per_batch": B * K,
-                   "cache_capacity": C, "devices": N_DEV,
+                   "cache_capacity": C, "devices": n_dev,
                    "iters": iters, "quick": quick},
         "entries": entries,
         "managed_faster_at_zipf_ge_1": all(
@@ -509,10 +527,10 @@ def _run_local(quick: bool, trace_path=None, metrics_path=None):
 
 def run(quick: bool = False, trace_path=None,
         metrics_path=None) -> List[str]:
-    import jax
-    if len(jax.devices()) < N_DEV:
+    n_dev = _mesh_size()
+    if not n_dev:
         return _reexec(quick, trace_path, metrics_path)
-    return _rows(_run_local(quick, trace_path, metrics_path))
+    return _rows(_run_local(quick, n_dev, trace_path, metrics_path))
 
 
 def check_baseline(path: str, pipeline: bool = False) -> int:
@@ -521,8 +539,8 @@ def check_baseline(path: str, pipeline: bool = False) -> int:
     and compare against the committed baseline, normalized through the
     paired in-process counterpart (machine-independent).  Returns a
     process exit code."""
-    import jax
-    if len(jax.devices()) < N_DEV:
+    n_dev = _mesh_size()
+    if not n_dev:
         # same one-attempt re-exec contract as `run` (see _reexec), but
         # propagating the guard's exit code instead of raising
         if os.environ.get("_MESH_BENCH_REEXEC"):
@@ -547,12 +565,12 @@ def check_baseline(path: str, pipeline: bool = False) -> int:
         if not committed:
             print(f"no pipeline section baseline in {path}")
             return 1
-        meas = _pipeline_arm(quick=True)["speedup"]
+        meas = _pipeline_arm(True, n_dev)["speedup"]
         print(f"pipeline arm: speedup now x{meas:.3f} vs committed "
               f"x{committed:.3f} (tolerance x{REGRESSION_TOL})")
         if committed / meas > REGRESSION_TOL:
             print("possible regression — re-measuring to filter noise")
-            meas = max(meas, _pipeline_arm(quick=True)["speedup"])
+            meas = max(meas, _pipeline_arm(True, n_dev)["speedup"])
             print(f"best-of-two: x{meas:.3f}")
         if committed / meas > REGRESSION_TOL:
             print(f"pipeline speedup regressed >15% vs {path}")
@@ -570,7 +588,7 @@ def check_baseline(path: str, pipeline: bool = False) -> int:
         relative to the committed baseline (>1 = slower than
         committed)."""
         ratios = {}
-        for e in _fused_arm(quick=True):
+        for e in _fused_arm(True, n_dev):
             if e["zipf"] not in base_entries:
                 continue
             b = base_entries[e["zipf"]]
@@ -623,6 +641,8 @@ if __name__ == "__main__":
                     help="write per-skew gauges as schema-versioned "
                     "JSONL to PATH")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.check_baseline:
         raise SystemExit(check_baseline(args.check_baseline,
                                         pipeline=args.pipeline))

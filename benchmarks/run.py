@@ -58,6 +58,8 @@ def main(argv=None):
                     help="comma-separated benchmark names")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (fig6_overall, fig7_scalability, fig8_timing,
                    fig15_traces, hotpath_bench, kernels_bench, mesh_bench,
                    quality_mf, scale_sweep, serve_bench,

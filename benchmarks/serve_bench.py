@@ -728,6 +728,8 @@ if __name__ == "__main__":
                     help="fail if tracing at default sampling costs >2%% "
                          "paired-median throughput")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.check_baseline:
         check_baseline(args.check_baseline, auto=args.auto,
                        pipeline=args.pipeline)

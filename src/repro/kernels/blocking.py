@@ -1,9 +1,10 @@
 """Tile-size selection for the row-blocked kernels: lane-aligned feature
-padding plus a small measured autotuner.
+padding, the HBM row group a DMA may move, and a small measured
+autotuner.
 
 Every row kernel in this package moves `(block_r, block_d)` tiles of row
 data (multi-row tiling — the grid is ``(ceil(n / block_r), D' / block_d)``,
-a ~block_r× smaller grid than the old one-row-per-program layout).  Two
+a ~block_r× smaller grid than the old one-row-per-program layout).  Three
 decisions live here so the kernels stay mechanical:
 
   feature dim   ``pad_d`` rounds D up to the next multiple of the 128-lane
@@ -16,13 +17,28 @@ decisions live here so the kernels stay mechanical:
                 in-place donation for that call) — keep embedding dims
                 lane-aligned on the hot path, padding is the correctness
                 escape hatch.
+  row group     A 2-D HBM array is stored in (8, 128) tiles of 32-bit
+                words (16 rows per tile for bf16, 32 for 8-bit types), and
+                Mosaic refuses a DMA whose row slice is not a whole number
+                of tiles: a single-row copy out of a (V, D) table does not
+                compile.  So the kernels DMA the ``row_group(dtype)`` rows
+                of the tile holding the wanted row and pick the row inside
+                VMEM (`kernels.rowdma`).  ``tile_pad`` pads an HBM operand
+                to whole tiles — a no-op when V and D are already aligned.
   tile shape    `pick_blocks` answers (block_r, block_d) per
                 (kind, n, d, dtype, backend).  The default is a cheap
                 heuristic; when measurement is enabled the caller hands in
                 a ``bench(block_r, block_d) -> seconds`` probe and the
                 result is cached per key, so each shape is measured once
-                per process (trace-time only — kernels re-trace per shape
-                anyway).
+                per process.  ``block_r`` is always one the Pallas
+                lowering accepts: a multiple of 8, or all ``n`` rows.
+
+Measuring happens only on concrete operands (`measurable`): under
+`jax.jit` tracing the operands are tracers, ``block_until_ready`` returns
+at once and a timing would measure tracing, so the wrappers pass no probe
+there and the heuristic answers (reported as ``source="heuristic"``).
+Probes run on a zero table of at most ``n`` rows (`probe_operand`), never
+on a copy of the full table.
 
 Overrides, strongest first: `set_block_override()` (config hook used by
 tests and launch scripts), then the ``REPRO_BLOCK_R`` / ``REPRO_BLOCK_D``
@@ -39,9 +55,10 @@ import os
 from typing import Callable, Dict, Optional, Tuple
 
 LANE = 128            # VREG lane width: feature tiles are multiples of this
+SUBLANE = 8           # rows of a 32-bit (8, 128) tile
 DEFAULT_BLOCK_D = 512  # cap on the feature-tile width
 DEFAULT_BLOCK_R = 8    # rows per program (multi-row tiling)
-_ROW_CANDIDATES = (1, 2, 4, 8, 16)
+_ROW_CANDIDATES = (8, 16, 32)
 
 _TUNE_CACHE: Dict[tuple, Tuple[int, int]] = {}
 _OVERRIDE: Dict[str, Optional[int]] = {"block_r": None, "block_d": None}
@@ -51,6 +68,24 @@ def pad_d(d: int) -> int:
     """Feature dim rounded up to the next multiple of the 128-lane width
     (the kernels pad their row data to this and slice the pad off)."""
     return -(-d // LANE) * LANE
+
+
+def row_group(dtype) -> int:
+    """Rows of one HBM tile of ``dtype`` — the smallest row slice a DMA
+    may move: 8 for 32-bit types, 16 for bf16, 32 for 8-bit types."""
+    import numpy as np
+    return SUBLANE * max(1, 4 // np.dtype(dtype).itemsize)
+
+
+def tile_pad(x, group: int):
+    """``x`` (R, D) zero-padded to whole HBM tiles: rows to a multiple of
+    ``group``, columns to `pad_d`.  Returns ``x`` itself when aligned."""
+    import jax.numpy as jnp
+    r, d = x.shape
+    rp, dp = -(-r // group) * group, pad_d(d)
+    if (rp, dp) == (r, d):
+        return x
+    return jnp.pad(x, ((0, rp - r), (0, dp - d)))
 
 
 def pick_block_d(d: int, block_d: int = DEFAULT_BLOCK_D) -> int:
@@ -69,6 +104,16 @@ def pick_block_d(d: int, block_d: int = DEFAULT_BLOCK_D) -> int:
     return best * LANE
 
 
+def legal_block_r(block_r: int, n: int) -> int:
+    """The nearest row-tile height the Pallas TPU lowering accepts for an
+    ``n``-row operand: a block's second-minor dim must be a multiple of 8
+    or the whole dim.  Rounds down to a multiple of 8 (at least 8), and
+    takes all ``n`` rows when that is no smaller."""
+    n = max(1, n)
+    br = max(SUBLANE, block_r // SUBLANE * SUBLANE)
+    return n if br >= n else br
+
+
 def set_block_override(block_r: Optional[int] = None,
                        block_d: Optional[int] = None) -> None:
     """Config hook: pin the tile shape globally (None clears a field).
@@ -81,12 +126,22 @@ def clear_autotune_cache() -> None:
     _TUNE_CACHE.clear()
 
 
-def probe_ids(n: int, n_rows: int):
-    """Row ids for an autotune measurement probe: spread over the table
-    (unique whenever n <= n_rows) so the timed DMA pattern resembles a
-    real scattered access, not n hits on row 0."""
+def measurable(*xs) -> bool:
+    """True when every operand is a concrete array, so a probe call can
+    be timed; False while a caller is being traced."""
+    import jax
+    return not any(isinstance(x, jax.core.Tracer) for x in xs)
+
+
+def probe_operand(n: int, n_rows: int, d: int, dtype):
+    """Zero table and row ids for an autotune probe: at most ``n`` rows
+    (bounded by the row operand the caller already holds, never the full
+    table), ids spread over it — unique whenever ``n <= n_rows`` — so the
+    timed DMA pattern is a scattered access, not n hits on row 0."""
     import jax.numpy as jnp
-    return (jnp.arange(n, dtype=jnp.int32) % max(1, n_rows))
+    rows = max(1, min(n, n_rows))
+    return (jnp.zeros((rows, d), dtype),
+            jnp.arange(n, dtype=jnp.int32) % rows)
 
 
 def time_bench(fn: Callable, iters: int = 3) -> float:
@@ -130,17 +185,18 @@ def pick_blocks(kind: str, n: int, d: int, dtype=None, *,
                 ) -> Tuple[int, int]:
     """Tile shape for an (n, d) row kernel: explicit args win, then the
     `set_block_override` / env overrides, then the measured cache, then
-    the heuristic.  ``bench(block_r, block_d) -> seconds`` enables the
-    measured path (see module docstring for the mode switch); results are
-    cached per (kind, n, d, dtype, table_rows, backend).
+    the heuristic.  Every answer passes through `legal_block_r`.
+    ``bench(block_r, block_d) -> seconds`` enables the measured path (see
+    module docstring for the mode switch; callers pass it only for
+    concrete operands); results are cached per (kind, n, d, dtype,
+    table_rows, backend).
 
     ``table_rows``: the height of the table-side operand (the gather /
-    scatter / update target).  It shapes the measured DMA pattern — the
-    probe spreads ids over the table — so it MUST be part of the cache
-    key: inside a `shard_map` the same (kind, n, d) call sees the
-    shard-local ``V / n_shards`` block, and a tile measured against the
-    full single-device V would otherwise be served stale to the mesh run
-    (and vice versa)."""
+    scatter / update target).  It MUST be part of the cache key: inside a
+    `shard_map` the same (kind, n, d) call sees the shard-local
+    ``V / n_shards`` block, and a tile measured against the full
+    single-device V would otherwise be served stale to the mesh run (and
+    vice versa)."""
     br = block_r if block_r is not None else \
         _OVERRIDE["block_r"] if _OVERRIDE["block_r"] is not None else \
         _env_int("REPRO_BLOCK_R")
@@ -149,22 +205,22 @@ def pick_blocks(kind: str, n: int, d: int, dtype=None, *,
         _env_int("REPRO_BLOCK_D")
     bd = pick_block_d(d, bd if bd is not None else DEFAULT_BLOCK_D)
     if br is not None:
-        return max(1, min(br, n)), bd
+        return legal_block_r(br, n), bd
 
     import jax
     key = (kind, n, d, str(dtype), table_rows, jax.default_backend(), bd)
     if key in _TUNE_CACHE:
         return _TUNE_CACHE[key]
     if _measure_enabled(bench):
-        timed = []
+        timed = {}
         for cand in _ROW_CANDIDATES:
-            if cand > max(1, n):
-                break
-            timed.append((bench(cand, bd), cand))
-        br = min(timed)[1] if timed else 1
+            cand = legal_block_r(cand, n)
+            if cand not in timed:
+                timed[cand] = bench(cand, bd)
+        br = min(timed, key=lambda c: (timed[c], c))
         source = "measured"
     else:
-        br = max(1, min(DEFAULT_BLOCK_R, n))
+        br = legal_block_r(DEFAULT_BLOCK_R, n)
         source = "heuristic"
     _TUNE_CACHE[key] = (br, bd)
     # every fresh tile decision lands on the process-wide signal bus

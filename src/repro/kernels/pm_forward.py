@@ -32,8 +32,9 @@ dense (T, D) table gather: hits read the replicated cache, misses read
 the compact (M+1, D) buffer (slot M is the all-zeros overflow/trash row).
 `pm_combine` moves it in (block_r, block_d) multi-row tiles: row indices
 are scalar-prefetched into SMEM and each grid program issues one guarded
-DMA per row — only the *winning* source row (cache or buffer) is staged
-into VMEM, half the bytes of the old stage-both layout.
+DMA per row, of the HBM tile that holds it (`kernels.rowdma`) — only the
+*winning* source (cache or buffer) is staged into VMEM, half the bytes of
+the old stage-both layout.
 """
 
 from __future__ import annotations
@@ -47,7 +48,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .blocking import pad_d, pick_blocks
+from .blocking import pick_blocks, row_group, tile_pad
+from .rowdma import at_row, tile_copy
 
 
 class ProbeCompact(NamedTuple):
@@ -176,41 +178,33 @@ def host_compact(cache_ids: np.ndarray, tok: np.ndarray,
 # ------------------------------------------------------------- pm_combine
 
 def _combine_kernel(hit_ref, cslot_ref, bslot_ref, cache_ref, buf_ref,
-                    out_ref, sem):
-    # multi-row tile: one guarded DMA per row, and only the WINNING source
-    # row (cache on hit, miss buffer otherwise) ever moves into VMEM
+                    out_ref, tile, sem):
+    # multi-row tile: one guarded tile DMA per row, and only the WINNING
+    # source (cache on hit, miss buffer otherwise) ever moves into VMEM
     i, j = pl.program_id(0), pl.program_id(1)
     block_r, block_d = out_ref.shape
+    group = tile.shape[0]
     T = hit_ref.shape[0]
+    col = pl.ds(j * block_d, block_d)
     for r in range(block_r):
         row = i * block_r + r
+
+        def fetch(src_ref, idx, r=r):
+            cp = tile_copy(src_ref, idx, group, col, tile, sem)
+            cp.start()
+            cp.wait()
+
+            def pick(s):
+                out_ref[pl.ds(r, 1), :] = tile[pl.ds(s, 1), :]
+
+            at_row(idx % group, group, pick)
 
         @pl.when(row < T)
         def _():
             hit = hit_ref[row] != 0
-
-            @pl.when(hit)
-            def _():
-                dma = pltpu.make_async_copy(
-                    cache_ref.at[cslot_ref[row],
-                                 pl.ds(j * block_d, block_d)],
-                    out_ref.at[r], sem)
-                dma.start()
-                dma.wait()
-
-            @pl.when(jnp.logical_not(hit))
-            def _():
-                dma = pltpu.make_async_copy(
-                    buf_ref.at[bslot_ref[row],
-                               pl.ds(j * block_d, block_d)],
-                    out_ref.at[r], sem)
-                dma.start()
-                dma.wait()
-
-
-def _pad_cols(x, dp):
-    d = x.shape[-1]
-    return x if d == dp else jnp.pad(x, ((0, 0), (0, dp - d)))
+            pl.when(hit)(lambda: fetch(cache_ref, cslot_ref[row]))
+            pl.when(jnp.logical_not(hit))(
+                lambda: fetch(buf_ref, bslot_ref[row]))
 
 
 @functools.partial(jax.jit,
@@ -219,26 +213,28 @@ def _pm_combine(hit, cache_slot, buf_slot, cache_rows, buf_rows,
                 block_r: int, block_d: int, interpret: bool):
     T = hit.shape[0]
     D = cache_rows.shape[1]
-    dp = pad_d(D)
+    group = row_group(cache_rows.dtype)
+    cache_rows = tile_pad(cache_rows, group)
+    buf_rows = tile_pad(buf_rows, group)
+    dp = cache_rows.shape[1]
     grid = (-(-T // block_r), dp // block_d)
+    HBM = pltpu.MemorySpace.HBM
     out = pl.pallas_call(
         _combine_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            ],
+            in_specs=[pl.BlockSpec(memory_space=HBM),
+                      pl.BlockSpec(memory_space=HBM)],
             out_specs=pl.BlockSpec((block_r, block_d),
                                    lambda i, j, h, s, p: (i, j)),
-            scratch_shapes=[pltpu.SemaphoreType.DMA],
+            scratch_shapes=[pltpu.VMEM((group, block_d), cache_rows.dtype),
+                            pltpu.SemaphoreType.DMA],
         ),
         out_shape=jax.ShapeDtypeStruct((T, dp), cache_rows.dtype),
         interpret=interpret,
     )(hit.astype(jnp.int32), cache_slot.astype(jnp.int32),
-      buf_slot.astype(jnp.int32), _pad_cols(cache_rows, dp),
-      _pad_cols(buf_rows, dp))
+      buf_slot.astype(jnp.int32), cache_rows, buf_rows)
     return out if dp == D else out[:, :D]
 
 
@@ -250,7 +246,10 @@ def pm_combine(hit: jnp.ndarray, cache_slot: jnp.ndarray,
     """Per-token select: out[i] = cache_rows[cache_slot[i]] on hit else
     buf_rows[buf_slot[i]].  cache_rows (C, D); buf_rows (M+1, D) with the
     trash row last; returns (T, D).  Tiled (block_r, block_d); the feature
-    dim is lane-padded, never shrunk (`kernels.blocking`)."""
+    dim is lane-padded, never shrunk (`kernels.blocking`).
+
+    The three (T,) index vectors are scalar-prefetched into SMEM, 12
+    bytes per token: a v5e core's 1 MiB of SMEM holds T up to ~87k."""
     br, bd = pick_blocks("pm_combine", hit.shape[0], cache_rows.shape[1],
                          cache_rows.dtype, table_rows=cache_rows.shape[0],
                          block_r=block_r, block_d=block_d)

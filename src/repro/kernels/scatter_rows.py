@@ -4,11 +4,14 @@ owner-sharded table gradient.
 Backward of the managed lookup: duplicate token gradients are pre-summed
 (`ops.segment_rows` fed by the step's sort residual — no extra sort), then
 this kernel writes each aggregated row into its table slot.  The dense
-(V, D) gradient is the donated zero buffer (``memory_space=ANY`` +
+(V, D) gradient is the donated zero buffer (``memory_space=HBM`` +
 input/output aliasing, in-place on TPU) and only the touched row tiles
-ever move: each grid program issues one guarded VMEM->HBM DMA per row of
-its ``(block_r, block_d)`` gradient tile (multi-row tiling, ~block_r×
-fewer grid programs than the old one-row layout).
+ever move: each grid program owns a ``(block_r, block_d)`` gradient tile
+and, per row, reads the HBM tile holding the target row, replaces the row
+in VMEM and writes the tile back (`kernels.rowdma`: Mosaic moves whole
+(8, 128) tiles only).  Copies are waited in row order and the grid is
+sequential, so a read always observes the preceding write, also when two
+target rows share a tile.
 
 Row ids must be unique; pad slots point at a caller-provided trash row
 (the managed path uses row V of a (V+1, D) buffer, sliced off afterwards),
@@ -24,23 +27,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .blocking import pad_d, pick_blocks
+from .blocking import (measurable, pick_blocks, probe_operand, row_group,
+                       tile_pad, time_bench)
+from .rowdma import at_row, tile_copy, tile_store
 
 
-def _scatter_kernel(ids_ref, base_ref, rows_ref, out_ref, sem):
+def _scatter_kernel(ids_ref, base_ref, rows_ref, out_ref, buf, sem):
     i, j = pl.program_id(0), pl.program_id(1)
     block_r, block_d = rows_ref.shape
+    group = buf.shape[0]
     n = ids_ref.shape[0]
+    col = pl.ds(j * block_d, block_d)
     for r in range(block_r):
         row = i * block_r + r
 
         @pl.when(row < n)
         def _():
-            dma = pltpu.make_async_copy(
-                rows_ref.at[r],
-                out_ref.at[ids_ref[row], pl.ds(j * block_d, block_d)], sem)
-            dma.start()
-            dma.wait()
+            idx = ids_ref[row]
+            cin = tile_copy(out_ref, idx, group, col, buf, sem)
+            cin.start()
+            cin.wait()
+
+            def put(s, r=r):
+                buf[pl.ds(s, 1), :] = rows_ref[pl.ds(r, 1), :]
+
+            at_row(idx % group, group, put)
+            cout = tile_store(buf, out_ref, idx, group, col, sem)
+            cout.start()
+            cout.wait()
 
 
 @functools.partial(jax.jit,
@@ -49,10 +63,12 @@ def _scatter_rows(base, ids, rows, block_r: int, block_d: int,
                   interpret: bool):
     n = ids.shape[0]
     R, D = base.shape
-    dp = pad_d(D)
+    group = row_group(base.dtype)
+    base = tile_pad(base, group)
+    dp = base.shape[1]
     if dp != D:
-        base = jnp.pad(base, ((0, 0), (0, dp - D)))
         rows = jnp.pad(rows, ((0, 0), (0, dp - D)))
+    HBM = pltpu.MemorySpace.HBM
     grid = (-(-n // block_r), dp // block_d)
     out = pl.pallas_call(
         _scatter_kernel,
@@ -60,18 +76,19 @@ def _scatter_rows(base, ids, rows, block_r: int, block_d: int,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),  # base
+                pl.BlockSpec(memory_space=HBM),                      # base
                 pl.BlockSpec((block_r, block_d),
-                             lambda i, j, ids_ref: (i, j)),           # rows
+                             lambda i, j, ids_ref: (i, j)),          # rows
             ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            scratch_shapes=[pltpu.SemaphoreType.DMA],
+            out_specs=pl.BlockSpec(memory_space=HBM),
+            scratch_shapes=[pltpu.VMEM((group, block_d), base.dtype),
+                            pltpu.SemaphoreType.DMA],
         ),
-        out_shape=jax.ShapeDtypeStruct((R, dp), base.dtype),
+        out_shape=jax.ShapeDtypeStruct(base.shape, base.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
     )(ids.astype(jnp.int32), base, rows.astype(base.dtype))
-    return out if dp == D else out[:, :D]
+    return out if out.shape == (R, D) else out[:R, :D]
 
 
 def scatter_rows(base: jnp.ndarray, ids: jnp.ndarray, rows: jnp.ndarray, *,
@@ -80,17 +97,15 @@ def scatter_rows(base: jnp.ndarray, ids: jnp.ndarray, rows: jnp.ndarray, *,
     """out = base with out[ids[i]] = rows[i]; base (R, D) is donated
     (in-place on TPU), ids (n,) int32 unique row indices, rows (n, D)."""
     n = ids.shape[0]
-    D = base.shape[1]
+    R, D = base.shape
+    bench = None
+    if measurable(base, ids, rows):
+        def bench(br, bd):
+            b, z = probe_operand(n, R, D, base.dtype)
+            return time_bench(
+                lambda: _scatter_rows(b, z, rows, br, bd, interpret))
 
-    def bench(br, bd):
-        from .blocking import probe_ids, time_bench
-        b = jnp.zeros(base.shape, base.dtype)
-        z = probe_ids(n, base.shape[0])
-        g = jnp.zeros(rows.shape, rows.dtype)
-        return time_bench(lambda: _scatter_rows(b, z, g, br, bd, interpret))
-
-    br, bd = pick_blocks("scatter", n, D, base.dtype,
-                         table_rows=base.shape[0], block_r=block_r,
-                         block_d=block_d, bench=bench)
+    br, bd = pick_blocks("scatter", n, D, base.dtype, table_rows=R,
+                         block_r=block_r, block_d=block_d, bench=bench)
     return _scatter_rows(base, ids, rows, block_r=br, block_d=bd,
                          interpret=interpret)

@@ -216,7 +216,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             p_sds["layers"])
         fsdp_spec = param_pspecs(layer_sds, cfg, mesh, zero_layers=False)
 
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_sds = jax.eval_shape(adagrad_init, p_sds)
             opt_spec = type(opt_sds)(accum=param_pspecs(

@@ -9,6 +9,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs.registry import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pm.controller import AUTO
 from repro.train.loop import LoopConfig, train_loop
 
@@ -54,6 +55,7 @@ def main(argv=None):
                     help="write per-phase spans (signal/plan/refresh/step) "
                          "as Chrome trace-event JSON to PATH")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     lc = LoopConfig(steps=args.steps, batch=args.batch, seq=args.seq,

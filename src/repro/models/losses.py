@@ -28,11 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.shard_map import shard_map  # type: ignore
-
 
 @partial(jax.custom_jvp, nondiff_argnums=(1,))
 def _pmax_stopgrad(x, axis_name):
@@ -81,7 +76,7 @@ def vocab_parallel_ce(h, head, labels, mesh, *, batch_axes: Tuple[str, ...],
         n = n_local * jax.lax.psum(jnp.ones((), jnp.float32), batch_axes)
         return total / n
 
-    loss = shard_map(
+    loss = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(None, model_axis),
                   P(batch_axes, None)),

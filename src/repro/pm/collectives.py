@@ -72,11 +72,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.shard_map import shard_map  # type: ignore
-
 from repro.kernels import ops, ref
 
 
@@ -243,7 +238,7 @@ class MeshBackend:
     explicit psum / psum_scatter.  Requires ``V % n_shards == 0`` (the
     same divisibility `models.losses.vocab_parallel_ce` asserts).
 
-    ``check_rep=False`` on the shard_maps: the Pallas gather kernel has no
+    ``check_vma=False`` on the shard_maps: the Pallas gather kernel has no
     replication rule, and the outputs' replication is structural (psum ->
     replicated, psum_scatter -> sharded by construction)."""
 
@@ -286,10 +281,10 @@ class MeshBackend:
                 tblk, jnp.clip(local, 0, block - 1), inb, use_pallas=kernel)
             return jax.lax.psum(rows, self.axis)
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=self.mesh,
             in_specs=(P(self.axis, None), P(None)), out_specs=P(None),
-            check_rep=False)(table, ids)
+            check_vma=False)(table, ids)
 
     def gather_rows_routed(self, table, ids, n_valid, *,
                            route_cap: int = 0, kernel: bool = False):
@@ -346,10 +341,10 @@ class MeshBackend:
                     rows_all.reshape(-1, D))
                 return buf[:M]
 
-            return shard_map(
+            return jax.shard_map(
                 f, mesh=self.mesh,
                 in_specs=(P(self.axis, None), P(None), P(None)),
-                out_specs=P(None), check_rep=False)(table, viewp, seg)
+                out_specs=P(None), check_vma=False)(table, viewp, seg)
 
         if cap >= M:        # the cap cannot be exceeded: no fallback arm
             return routed(None)
@@ -398,9 +393,9 @@ class MeshBackend:
             return jnp.zeros((block, D), gp.dtype).at[
                 jnp.where(ok, local, block)].add(recv_g, mode="drop")
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=self.mesh, in_specs=(P(None), P(None)),
-            out_specs=P(self.axis, None), check_rep=False)(tokp, gp)
+            out_specs=P(self.axis, None), check_vma=False)(tokp, gp)
 
     def scatter_row_grads_psum(self, tok, g, vocab_size: int, *,
                                kernel: bool = False,
@@ -433,9 +428,9 @@ class MeshBackend:
             return jax.lax.psum_scatter(partial, self.axis,
                                         scatter_dimension=0, tiled=True)
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=self.mesh, in_specs=(P(None), P(None)),
-            out_specs=P(self.axis, None), check_rep=False)(tokp, gp)
+            out_specs=P(self.axis, None), check_vma=False)(tokp, gp)
 
     def update_rows(self, table, accum, seg_ids, seg_g, *, lr: float,
                     eps: float = 1e-8, kernel: bool = False):
@@ -477,12 +472,12 @@ class MeshBackend:
             return ref.adagrad_row_add_ref(tblk, ablk, ids_l, g_l,
                                            lr=lr, eps=eps)
 
-        return shard_map(
+        return jax.shard_map(
             f, mesh=self.mesh,
             in_specs=(P(self.axis, None), P(self.axis, None), P(None),
                       P(None)),
             out_specs=(P(self.axis, None), P(self.axis, None)),
-            check_rep=False)(table, accum, tokp, gp)
+            check_vma=False)(table, accum, tokp, gp)
 
     def refresh_rows(self, table, cache_ids):
         """Replica sync round: the grouped all-gather of the plan's hot

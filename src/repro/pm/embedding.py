@@ -45,6 +45,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
 from repro.kernels.pm_forward import (StepResidual, host_compact,
@@ -103,8 +104,14 @@ def combine_miss_buffer(backend, table, cache_rows, hit, cache_slot,
         buf_rows = be.gather_rows(table, buf_ids, kernel=kernel)
     buffer = jnp.concatenate(
         [buf_rows, jnp.zeros((1, table.shape[1]), buf_rows.dtype)])
-    return ops.pm_combine(hit, cache_slot, buf_slot, cache_rows, buffer,
-                          use_pallas=kernel)
+    combine = functools.partial(ops.pm_combine, use_pallas=kernel)
+    if kernel and getattr(be, "mesh_real", False):
+        # XLA never partitions a Pallas kernel: on a mesh, every device
+        # runs the combine over the replicated operands, as it would the
+        # jnp select
+        combine = jax.shard_map(combine, mesh=be.mesh, in_specs=P(),
+                                out_specs=P(), check_vma=False)
+    return combine(hit, cache_slot, buf_slot, cache_rows, buffer)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.kernels import ops
 from repro.pm.collectives import (EMULATED, EmulatedBackend, MeshBackend,
@@ -386,9 +387,9 @@ def _sorts_in(jaxpr) -> int:
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else [v]
             for x in vs:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, ClosedJaxpr):
                     n += _sorts_in(x.jaxpr)
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, Jaxpr):
                     n += _sorts_in(x)
     return n
 
@@ -409,9 +410,9 @@ def _dense_rows_in(jaxpr, vocab: int) -> list:
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else [v]
             for x in vs:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, ClosedJaxpr):
                     bad += _dense_rows_in(x.jaxpr, vocab)
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, Jaxpr):
                     bad += _dense_rows_in(x, vocab)
     return bad
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.kernels import blocking, ops, ref
 from repro.kernels.adagrad_rows import adagrad_row_update
@@ -35,9 +36,9 @@ def _count_sorts(jaxpr) -> int:
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else [v]
             for x in vs:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, ClosedJaxpr):
                     n += _count_sorts(x.jaxpr)
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, Jaxpr):
                     n += _count_sorts(x)
     return n
 
@@ -123,6 +124,18 @@ class TestMultiRowTiles:
         out = embed_gather(table, ids, block_r=block_r, interpret=True)
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(ref.embed_gather_ref(table, ids)))
+
+    def test_gather_clamps_out_of_range_ids(self):
+        """Id buffers padded with V (the serving runtime's residual and
+        staging buffers) must not DMA past the table — on the chip that
+        halts the core.  They read the clamped row, as XLA's gather."""
+        V, D = 64, 128
+        table = jnp.asarray(np.random.default_rng(4).normal(size=(V, D)),
+                            jnp.float32)
+        ids = jnp.asarray([3, V, V + 7, -1], jnp.int32)
+        out = embed_gather(table, ids, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(table)[[3, V - 1, V - 1, 0]])
 
     @pytest.mark.parametrize("V,D,n,block_r", ODD_SHAPES)
     def test_scatter_matches_ref(self, V, D, n, block_r):
@@ -228,13 +241,13 @@ class TestBlockAutotuner:
         assert blocking.pick_block_d(64, 512) == 128
 
     def test_override_precedence(self):
-        blocking.set_block_override(block_r=2, block_d=256)
+        blocking.set_block_override(block_r=16, block_d=256)
         try:
             br, bd = blocking.pick_blocks("t", 64, 512, "f32")
-            assert (br, bd) == (2, 256)
+            assert (br, bd) == (16, 256)
             # explicit args beat the override
-            br, bd = blocking.pick_blocks("t", 64, 512, "f32", block_r=4)
-            assert br == 4
+            br, bd = blocking.pick_blocks("t", 64, 512, "f32", block_r=32)
+            assert br == 32
         finally:
             blocking.set_block_override()
 
@@ -245,16 +258,16 @@ class TestBlockAutotuner:
 
         def bench(br, bd):
             calls.append((br, bd))
-            return {1: 5.0, 2: 1.0, 4: 3.0, 8: 9.0, 16: 9.0}[br]
+            return {8: 5.0, 16: 1.0, 32: 3.0}[br]
 
-        br, bd = blocking.pick_blocks("bench-test", 16, 256, "f32",
+        br, bd = blocking.pick_blocks("bench-test", 64, 256, "f32",
                                       bench=bench)
-        assert br == 2 and bd == 256
+        assert br == 16 and bd == 256
         n_calls = len(calls)
         assert n_calls >= 2            # it really measured candidates
-        br2, _ = blocking.pick_blocks("bench-test", 16, 256, "f32",
+        br2, _ = blocking.pick_blocks("bench-test", 64, 256, "f32",
                                       bench=bench)
-        assert br2 == 2 and len(calls) == n_calls   # second hit cached
+        assert br2 == 16 and len(calls) == n_calls   # second hit cached
         blocking.clear_autotune_cache()
 
     def test_cache_key_includes_table_rows(self, monkeypatch):
@@ -268,26 +281,26 @@ class TestBlockAutotuner:
 
         def bench_full(br, bd):
             calls.append(("full", br))
-            return {1: 5.0, 2: 1.0, 4: 3.0, 8: 9.0, 16: 9.0}[br]
+            return {8: 5.0, 16: 1.0, 32: 3.0}[br]
 
         def bench_shard(br, bd):
             calls.append(("shard", br))
-            return {1: 5.0, 2: 3.0, 4: 1.0, 8: 9.0, 16: 9.0}[br]
+            return {8: 5.0, 16: 3.0, 32: 1.0}[br]
 
-        br_full, _ = blocking.pick_blocks("rows-test", 16, 256, "f32",
+        br_full, _ = blocking.pick_blocks("rows-test", 64, 256, "f32",
                                           table_rows=1024,
                                           bench=bench_full)
-        br_shard, _ = blocking.pick_blocks("rows-test", 16, 256, "f32",
+        br_shard, _ = blocking.pick_blocks("rows-test", 64, 256, "f32",
                                            table_rows=128,
                                            bench=bench_shard)
-        assert br_full == 2 and br_shard == 4   # measured independently
+        assert br_full == 16 and br_shard == 32  # measured independently
         n_calls = len(calls)
-        assert blocking.pick_blocks("rows-test", 16, 256, "f32",
+        assert blocking.pick_blocks("rows-test", 64, 256, "f32",
                                     table_rows=1024,
-                                    bench=bench_full)[0] == 2
-        assert blocking.pick_blocks("rows-test", 16, 256, "f32",
+                                    bench=bench_full)[0] == 16
+        assert blocking.pick_blocks("rows-test", 64, 256, "f32",
                                     table_rows=128,
-                                    bench=bench_shard)[0] == 4
+                                    bench=bench_shard)[0] == 32
         assert len(calls) == n_calls            # both served from cache
         blocking.clear_autotune_cache()
 
@@ -301,4 +314,43 @@ class TestBlockAutotuner:
         br, bd = blocking.pick_blocks("off-test", 64, 512, "f32",
                                       bench=bench)
         assert br == blocking.DEFAULT_BLOCK_R and bd == 512
+        blocking.clear_autotune_cache()
+
+    @pytest.mark.parametrize("n", [1, 5, 8, 13, 31, 64, 8192])
+    @pytest.mark.parametrize("block_r", [None, 1, 2, 3, 4, 12, 16, 100])
+    def test_never_returns_a_block_r_the_lowering_refuses(self, n,
+                                                          block_r):
+        """A row block must be a multiple of 8 or all n rows (the Pallas
+        TPU lowering's (8, 128) rule), whatever the caller, override or
+        measurement asked for."""
+        blocking.clear_autotune_cache()
+        for mode in ("off", "measure"):
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setenv("REPRO_AUTOTUNE", mode)
+                br, _ = blocking.pick_blocks(
+                    f"legal-{mode}", n, 256, "f32", block_r=block_r,
+                    bench=lambda r, d: 1.0 / r)
+            assert br == n or (br % 8 == 0 and br < n), (mode, br)
+        blocking.clear_autotune_cache()
+
+    def test_never_measures_under_jit_tracing(self, monkeypatch):
+        """Inside `jax.jit` the operands are tracers: timing a probe there
+        measures tracing, not the kernel.  The wrappers fall back to the
+        heuristic and report it as such."""
+        import repro.kernels.embed_gather as gather_mod
+        from repro.obs.telemetry import default_bus
+        monkeypatch.setenv("REPRO_AUTOTUNE", "measure")
+        blocking.clear_autotune_cache()
+        timed = []
+        monkeypatch.setattr(gather_mod, "time_bench",
+                            lambda fn, iters=3: timed.append(fn) or 1.0)
+        seen = len(default_bus().events("autotune.blocks"))
+        table = jnp.zeros((96, 128), jnp.float32)
+        ids = jnp.arange(40, dtype=jnp.int32) % 96
+        out = jax.jit(lambda t, i: embed_gather(t, i, interpret=True))(
+            table, ids)
+        assert out.shape == (40, 128)
+        assert timed == []
+        events = default_bus().events("autotune.blocks")[seen:]
+        assert [e["source"] for e in events] == ["heuristic"]
         blocking.clear_autotune_cache()
