@@ -1,0 +1,7 @@
+"""Device busy time per executed batch in the traced window (ms)."""
+
+import readers
+
+
+def read(ctx):
+    return readers.device_ms_per_batch(ctx)
