@@ -1,0 +1,16 @@
+"""Host ms per replan spent solving the plan (intent snapshot through
+the last replan_from_queue): the summed length of the runtime's
+serve.plan.solve spans in the window over its serve.plan spans there.
+None where the run recorded no serve.plan.solve span (a program without
+it)."""
+
+SPAN = "serve.plan.solve"
+
+
+def read(ctx):
+    if not any(s[0] == SPAN for s in ctx.spans):
+        return None
+    plans = len(ctx.window_spans("serve.plan"))
+    if not plans:
+        return None
+    return sum(s[2] - s[1] for s in ctx.window_spans(SPAN)) / 1e6 / plans

@@ -353,7 +353,8 @@ def _traced_arm(table, trace_path, metrics_path) -> None:
     metrics/attribution sink (the report CLI's and CI's artifacts)."""
     replay = _record(1.1, 12)
     tracer = SpanTracer()
-    rt = ServingRuntime(table, _tuned_cfg(), tracer=tracer)
+    rt = ServingRuntime(table, replace(_tuned_cfg(), trace=True),
+                        tracer=tracer)
     res = rt.run(replay, ROUNDS, warmup_backlog=BACKLOG,
                  measure_from=MEASURE_FROM)
     assert len(rt.attribution.records) == res.replans, \

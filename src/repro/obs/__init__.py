@@ -8,9 +8,11 @@ the same records to adapt runtime knobs — one signal path instead of
 ad-hoc prints and scattered result fields.
 
 Above the bus: `SpanTracer` (ring-buffered per-request/per-phase spans,
-Chrome-trace export), `PlanAttribution` (plan-vs-actual accounting at
-replan boundaries), `prometheus_text`/`JsonlSink` (scrape/file export),
-and ``python -m repro.obs.report`` (the shutdown report renderer).
+mirrored onto the profiler's clock, Chrome-trace export; `watch_compiles`
+counts compiles on the bus), `PlanAttribution` (plan-vs-actual
+accounting at replan boundaries), `prometheus_text`/`JsonlSink`
+(scrape/file export), and ``python -m repro.obs.report`` (the shutdown
+report renderer).
 """
 
 from repro.obs.attribution import (ATTRIBUTION_SCHEMA, AttributionRecord,

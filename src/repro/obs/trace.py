@@ -28,6 +28,12 @@ ride in the arrays):
             serve.dispatch / serve.served, per-request
             ``serve.request`` (enqueue -> served, a=rid b=attempts) and
             ``serve.requeue`` instant points (a=rid)
+  replan    serve.plan > ``serve.plan.solve`` (intent snapshot through
+            the last ``replan_from_queue``, a capacity re-plan
+            included) / ``serve.plan.probe_view`` (the `CacheProbeView`
+            build, only when the cache ids changed) / ``serve.refresh``
+            (the replica re-gather's host dispatch; between replans too
+            when ``refresh_every > 0``) / ``prefetch.stage`` (a=round)
   training  train.signal / train.plan / train.refresh / train.step
             (a=step)
   prefetch  the ISSUE-9 pipeline stages (DESIGN.md §15):
@@ -38,6 +44,25 @@ ride in the arrays):
             ``prefetch.drain`` — a deferred step's loss block (a=step);
             ``prefetch.stage`` — the serving tenure's staging-buffer
             gather (a=round)
+  compiles  ``jit.compile`` — one backend compile or persistent-cache
+            read, over [end - duration, end] (see `watch_compiles`)
+
+Profiler mirror: an enabled tracer's `span()` also enters
+``jax.profiler.TraceAnnotation(name)`` around the block, so a profiler
+capture (xprof, Perfetto) shows every ``serve.*``, ``train.*`` and
+``prefetch.*`` phase on the host thread, on the device trace's clock.
+`record`, `record_many` and `point` are retroactive or instant and stay
+in the ring only.  jax is imported on the first enabled span, so this
+module imports without it; a disabled span touches neither.
+
+Compiles: `watch_compiles` hooks one process-wide ``jax.monitoring``
+duration listener (registered once) to the two events that mark a
+compile — a backend compile, or an executable read from the persistent
+cache — and forwards each to every live watch: the unlabelled counter
+``jit.compiles`` on its bus and, when its tracer is enabled, a
+``jit.compile`` span.  Watches are held weakly (a runtime keeps its
+own; the train loop one for the loop's length), and live watches that
+share a bus or a tracer count each compile once there.
 
 `to_chrome()` renders the buffer as Chrome trace-event JSON ("X"
 complete events + "i" instants, ts/dur in microseconds) — loadable in
@@ -48,13 +73,29 @@ into the shutdown latency report.
 from __future__ import annotations
 
 import json
+import threading
 import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
 
 _DEFAULT_CAPACITY = 1 << 15
+
+# jax.profiler.TraceAnnotation, resolved on the first enabled span
+# (None: not yet; False: jax is not installed)
+_annotate = None
+
+
+def _resolve_annotate():
+    global _annotate
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        TraceAnnotation = False
+    _annotate = TraceAnnotation
+    return TraceAnnotation
 
 
 class _NullSpan:
@@ -73,9 +114,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """An open span: records itself into the ring on exit."""
+    """An open span: mirrored onto the profiler's clock while open,
+    recorded into the ring on exit."""
 
-    __slots__ = ("_tr", "_name", "_tid", "_a", "_b", "_t0")
+    __slots__ = ("_tr", "_name", "_tid", "_a", "_b", "_t0", "_ann")
 
     def __init__(self, tr: "SpanTracer", name: str, tid: int,
                  a: int, b: int):
@@ -85,14 +127,21 @@ class _Span:
         self._a = a
         self._b = b
         self._t0 = 0
+        self._ann = None
 
     def __enter__(self):
+        ann = _annotate if _annotate is not None else _resolve_annotate()
+        if ann:
+            self._ann = ann(self._name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         self._tr.record(self._name, self._t0, time.perf_counter_ns(),
                         tid=self._tid, a=self._a, b=self._b)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -184,8 +233,9 @@ class SpanTracer:
         self.record(name, t, t, tid=tid, a=a, b=b)
 
     def span(self, name: str, *, tid: int = 0, a: int = 0, b: int = 0):
-        """Context manager measuring the enclosed block.  Disabled
-        tracers return one shared no-op — no allocation, no clock."""
+        """Context manager measuring the enclosed block, mirrored as a
+        profiler `TraceAnnotation`.  Disabled tracers return one shared
+        no-op — no allocation, no clock, no annotation."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, tid, a, b)
@@ -266,3 +316,54 @@ def make_tracer(enabled: bool, sample: float = 1.0,
     if tracer is not None:
         return tracer
     return SpanTracer(capacity=capacity, sample=sample, enabled=enabled)
+
+
+COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
+                  "/jax/compilation_cache/cache_retrieval_time_sec")
+
+
+class CompileWatch:
+    """A bus and a tracer that hear of every compile while this lives."""
+
+    __slots__ = ("telemetry", "tracer", "__weakref__")
+
+    def __init__(self, telemetry, tracer: SpanTracer):
+        self.telemetry = telemetry
+        self.tracer = tracer
+
+
+_watches: "weakref.WeakSet[CompileWatch]" = weakref.WeakSet()
+_watch_lock = threading.Lock()
+_listening = False
+
+
+def watch_compiles(telemetry, tracer: SpanTracer) -> CompileWatch:
+    """Count every compile of this process on ``telemetry`` (counter
+    ``jit.compiles``) and, while ``tracer`` is enabled, record it as a
+    ``jit.compile`` span, for as long as the caller holds the returned
+    watch.  The first call registers the one listener."""
+    global _listening
+    w = CompileWatch(telemetry, tracer)
+    with _watch_lock:
+        _watches.add(w)
+        if not _listening:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_duration)
+            _listening = True
+    return w
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    if event not in COMPILE_EVENTS:
+        return
+    t1 = time.perf_counter_ns()
+    with _watch_lock:
+        live = list(_watches)
+    buses = {id(w.telemetry): w.telemetry for w in live}
+    tracers = {id(w.tracer): w.tracer for w in live if w.tracer.enabled}
+    for bus in buses.values():
+        bus.inc("jit.compiles")
+    t0 = t1 - int(secs * 1e9)
+    for tr in tracers.values():
+        tr.record("jit.compile", t0, t1)

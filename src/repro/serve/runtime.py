@@ -81,7 +81,7 @@ import numpy as np
 from repro.core.engine import StreamingIntentBuffer
 from repro.obs.attribution import PlanAttribution
 from repro.obs.telemetry import Telemetry
-from repro.obs.trace import SpanTracer, make_tracer
+from repro.obs.trace import SpanTracer, make_tracer, watch_compiles
 from repro.pm.collectives import resolve
 from repro.pm.controller import (AUTO, Knob, OnlineController, capacity_ladder,
                                  is_auto, overlap_pays, pow2_ladder,
@@ -140,7 +140,9 @@ class ServeConfig:
     #   the end of the runtime's first run (the shutdown line)
     trace: bool = False          # span tracing (DESIGN.md §14): default
     #   OFF — disabled call sites cost one early-return branch; enabled
-    #   at trace_sample=1.0 the serve bench pins the cost under 2%
+    #   at trace_sample=1.0 the serve bench pins the cost under 2%.  Also
+    #   the switch of plan attribution, which an injected tracer alone
+    #   does not turn on
     trace_sample: float = 1.0    # deterministic per-rid sampling for
     #   request spans (phase spans always record when tracing is on)
     trace_capacity: int = 1 << 15  # span ring size (oldest spans evicted)
@@ -209,6 +211,9 @@ class ServingRuntime:
         # across runtimes); otherwise built from the cfg — default off
         self.tracer = make_tracer(cfg.trace, cfg.trace_sample,
                                   cfg.trace_capacity, tracer)
+        # every compile while this runtime lives: `jit.compiles` on its
+        # bus, a `jit.compile` span when traced
+        self._compile_watch = watch_compiles(self.telemetry, self.tracer)
         from repro.pm.collectives import make_backend
         self.backend = make_backend(cfg.collective, cfg.model_shards)
         if self.backend is not None:
@@ -293,12 +298,13 @@ class ServingRuntime:
             plan_every=self.replan_every,
             owner_shards=self._owner_shards,
             telemetry=self.telemetry) if cfg.managed else None
-        # plan-vs-actual audit trail (DESIGN.md §14): only when traced —
-        # one record per replan boundary, over the same bus
+        # plan-vs-actual audit trail (DESIGN.md §14): only when the
+        # operator asks for tracing (cfg.trace; an injected tracer alone
+        # does not turn it on) — one record per replan boundary
         self.attribution: Optional[PlanAttribution] = (
             PlanAttribution(owner_shards=self._owner_shards,
                             vocab=cfg.vocab, telemetry=self.telemetry)
-            if cfg.managed and self.tracer.enabled else None)
+            if cfg.managed and cfg.trace else None)
         self.plan: Optional[PlacementPlan] = None
         self._cache_ids = None           # device copy (refresh input)
         self._cache_ids_np = None        # host copy (admission-time probe)
@@ -540,22 +546,25 @@ class ServingRuntime:
     def _replan(self, rnd: int, res: ServeResult, cause: str) -> None:
         old_plan = self.plan     # the tenure the attribution flush closes
         self._controller_step(rnd, res)
-        keys, slots, ticks = self.intent.snapshot(
-            self.queue.order_ids(), self.batch_requests)
-        if len(keys) == 0:
-            return
-        plan = self.planner.replan_from_queue(keys, slots, ticks)
-        if self._ctl is not None and "cache_capacity" in self._auto:
-            # intent-signal capacity steering: the plan's demand count IS
-            # the bucket; a changed bucket re-plans over the same snapshot
-            # so plan/ids/rows stay mutually consistent
-            new_cap = self._ctl.steer_capacity("cache_capacity",
-                                               plan.demand)
-            if new_cap is not None:
-                self._set_capacity(int(new_cap), rnd)
-                res.capacity_resizes += 1
-                res.capacity_trace.append((rnd, int(new_cap)))
-                plan = self.planner.replan_from_queue(keys, slots, ticks)
+        tr = self.tracer
+        with tr.span("serve.plan.solve", a=rnd):
+            keys, slots, ticks = self.intent.snapshot(
+                self.queue.order_ids(), self.batch_requests)
+            if len(keys) == 0:
+                return
+            plan = self.planner.replan_from_queue(keys, slots, ticks)
+            if self._ctl is not None and "cache_capacity" in self._auto:
+                # intent-signal capacity steering: the plan's demand count
+                # IS the bucket; a changed bucket re-plans over the same
+                # snapshot so plan/ids/rows stay mutually consistent
+                new_cap = self._ctl.steer_capacity("cache_capacity",
+                                                   plan.demand)
+                if new_cap is not None:
+                    self._set_capacity(int(new_cap), rnd)
+                    res.capacity_resizes += 1
+                    res.capacity_trace.append((rnd, int(new_cap)))
+                    plan = self.planner.replan_from_queue(keys, slots,
+                                                          ticks)
         # a replan that kept the cache contents (sorted ids are canonical,
         # so set-equality IS array-equality) needs no re-gather when the
         # serving table is declared read-only (refresh_every == 0: no
@@ -573,16 +582,17 @@ class ServingRuntime:
             self._cache_ids = jnp.asarray(self.plan.cache_ids)
             # new cache generation: rebuild the memoized probe LUTs once
             # (the per-batch probe never re-sorts the cache side again)
-            self._probe_view = CacheProbeView(self._cache_ids_np,
-                                              self.cfg.vocab)
+            with tr.span("serve.plan.probe_view", a=rnd):
+                self._probe_view = CacheProbeView(self._cache_ids_np,
+                                                  self.cfg.vocab)
             self._staged_ids = None      # rebuilt below for the new tenure
-            self._refresh(res)
+            self._refresh(res, rnd)
         # per-tenure staged prefetch (DESIGN.md §15): the snapshot's
         # queued-horizon keys the new plan does NOT cache are exactly this
         # tenure's predicted miss set — gather them once into the staging
         # buffer so steady-state batches skip the per-batch collective
         if self.pipeline_depth >= 1:
-            with self.tracer.span("prefetch.stage", a=rnd):
+            with tr.span("prefetch.stage", a=rnd):
                 self._stage(keys)
         else:
             self._staged_ids = None
@@ -721,22 +731,27 @@ class ServingRuntime:
         self.telemetry.inc("serve.stage_topups")
         self.telemetry.inc("serve.stage_topup_rows", int(new_ids.size))
 
-    def _refresh(self, res: ServeResult) -> None:
+    def _refresh(self, res: ServeResult, rnd: int) -> None:
+        """Re-gather the replica cache (and the staging buffer) from the
+        table.  Its ``serve.refresh`` span times the host's dispatch
+        only: the gathers run asynchronously, and their device time is
+        in the device trace."""
         # eager on purpose (emulated): the XLA CPU backend lowers the
         # jitted clip+gather+mask into a far slower fused gather than the
         # op-by-op eager dispatch (measured 35ms vs 2.3ms for a
         # (4096, 512) cache); the mesh backend's refresh is the grouped
         # all-gather shard_map, eager too
-        self._cache_rows = resolve(self.backend).refresh_rows(
-            self.table, self._cache_ids)
-        if self._staged_ids is not None:
-            # the staging buffer obeys the same staleness bound as the
-            # replica cache: re-gathered on every refresh round, so an
-            # out-of-band table update reaches staged rows within one
-            self._staging_rows = resolve(self.backend).refresh_rows(
-                self.table, self._staged_ids_dev)
-            self._cache_ext = jnp.concatenate([self._cache_rows,
-                                               self._staging_rows])
+        with self.tracer.span("serve.refresh", a=rnd):
+            self._cache_rows = resolve(self.backend).refresh_rows(
+                self.table, self._cache_ids)
+            if self._staged_ids is not None:
+                # the staging buffer obeys the same staleness bound as the
+                # replica cache: re-gathered on every refresh round, so an
+                # out-of-band table update reaches staged rows within one
+                self._staging_rows = resolve(self.backend).refresh_rows(
+                    self.table, self._staged_ids_dev)
+                self._cache_ext = jnp.concatenate([self._cache_rows,
+                                                   self._staging_rows])
         res.refreshes += 1
         self.telemetry.inc("serve.refreshes")
 
@@ -854,7 +869,7 @@ class ServingRuntime:
                 elif self.plan is not None and self.refresh_every > 0 \
                         and rnd - last_replan > 0 \
                         and (rnd - last_replan) % self.refresh_every == 0:
-                    self._refresh(res)
+                    self._refresh(res, rnd)
 
             batch = self.scheduler.admit(self.queue)
             if batch is None or (cfg.managed and self.plan is None):

@@ -52,7 +52,7 @@ from repro.configs.base import ModelConfig
 from repro.data.pipeline import IntentSignalingLoader
 from repro.models.model import init_model
 from repro.obs.telemetry import Telemetry
-from repro.obs.trace import SpanTracer, make_tracer
+from repro.obs.trace import SpanTracer, make_tracer, watch_compiles
 from repro.pm.controller import (AUTO, Knob, OnlineController,
                                  capacity_ladder, is_auto, resolve_knob)
 from repro.pm.embedding import make_state
@@ -129,6 +129,9 @@ def train_loop(cfg: ModelConfig, lc: LoopConfig,
     # per-phase span tracing (DESIGN.md §14): default-off no-op unless
     # the caller injects an enabled tracer (launch/train.py --trace)
     tr = make_tracer(False, tracer=tracer)
+    # every compile of the loop: `jit.compiles` on the bus, a
+    # `jit.compile` span when traced (held until the loop returns)
+    compile_watch = watch_compiles(bus, tr)
     key = jax.random.PRNGKey(lc.seed)
     params = init_model(cfg, key)
     opt_state = make_opt_init(lc.optimizer)(params)
@@ -469,6 +472,7 @@ def train_loop(cfg: ModelConfig, lc: LoopConfig,
     if executor is not None:
         executor.shutdown(wait=True)
 
+    del compile_watch
     res.recompiles = len(step_fns)
     res.wall_s = time.time() - t0
     res.knobs = {"cache_capacity": cache_capacity,

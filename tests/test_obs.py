@@ -225,21 +225,46 @@ class TestTenantAccounting:
         json.dumps(bus.snapshot())
 
 
-def _traced_run(rounds=28, **cfg_kw):
+def _traced_run(rounds=28, stream=None, tracer=None, **cfg_kw):
     rng = np.random.default_rng(0)
     table = rng.normal(size=(2048, 8)).astype(np.float32)
     kw = dict(vocab=2048, batch_requests=16, keys_per_request=8,
               cache_capacity=256, replan_every=6, trace=True)
     kw.update(cfg_kw)
     cfg = ServeConfig(**kw)
-    stream = DriftingZipfStream(2048, kw["keys_per_request"],
-                                zipf_a=1.2,
-                                arrival_rate=kw["batch_requests"],
-                                scenario="rotate", rotate_every=10,
-                                seed=5)
-    rt = ServingRuntime(table, cfg)
+    if stream is None:
+        stream = DriftingZipfStream(2048, kw["keys_per_request"],
+                                    zipf_a=1.2,
+                                    arrival_rate=kw["batch_requests"],
+                                    scenario="rotate", rotate_every=10,
+                                    seed=5)
+    rt = ServingRuntime(table, cfg, tracer=tracer)
     res = rt.run(stream, rounds)
     return rt, res
+
+
+class _Repeat:
+    """The same 16 requests' keys every round: the queued horizon, and
+    with it the plan's cache ids, never change after the first replan."""
+
+    def __init__(self, keys_per_request=8, n=16):
+        self.toks = np.random.default_rng(3).integers(
+            0, 64, size=(n, keys_per_request))
+        self.rid = 0
+
+    def arrivals(self, rnd):
+        out = [ServeRequest(self.rid + i, t) for i, t in
+               enumerate(self.toks)]
+        self.rid += len(out)
+        return out
+
+
+def _spans(rt, name):
+    return [e for e in rt.tracer.events() if e["name"] == name]
+
+
+def _inside(e, outer):
+    return outer["t0_ns"] <= e["t0_ns"] and e["t1_ns"] <= outer["t1_ns"]
 
 
 class TestTracedServe:
@@ -269,6 +294,170 @@ class TestTracedServe:
         rt, _ = _traced_run(rounds=8, trace=False)
         assert rt.attribution is None
         assert rt.tracer.count == 0
+
+
+class TestPlanParts:
+    """The replan's parts as spans (DESIGN.md §14), their profiler
+    mirror, and the compile listener."""
+
+    @pytest.mark.parametrize("refresh_every", [0, 2])
+    def test_parts_lie_inside_their_replan_or_refresh_round(
+            self, refresh_every):
+        rt, res = _traced_run(refresh_every=refresh_every)
+        plans = _spans(rt, "serve.plan")
+        assert len(plans) == res.replans >= 2
+        replan_rounds = sorted(p["a"] for p in plans)
+        for name in ("serve.plan.solve", "serve.plan.probe_view",
+                     "serve.refresh", "prefetch.stage"):
+            for e in _spans(rt, name):
+                owner = [p for p in plans if _inside(e, p)]
+                if owner:
+                    assert owner[0]["a"] == e["a"]
+                    continue
+                # a refresh round between replans
+                assert name == "serve.refresh" and refresh_every > 0
+                last = max(r for r in replan_rounds if r < e["a"])
+                assert (e["a"] - last) % refresh_every == 0
+        for p in plans:
+            solves = [e for e in _spans(rt, "serve.plan.solve")
+                      if _inside(e, p)]
+            assert len(solves) == 1
+        assert len(_spans(rt, "serve.refresh")) == res.refreshes
+        if refresh_every:
+            assert res.refreshes > res.replans
+
+    @pytest.mark.parametrize("stream", ["rotate", "repeat"])
+    def test_probe_view_is_built_exactly_when_the_cache_ids_change(
+            self, monkeypatch, stream):
+        from repro.pm.planner import IntentPlanner
+        real = IntentPlanner.replan_from_queue
+        ids = []
+
+        def recording(self, *a, **k):
+            plan = real(self, *a, **k)
+            ids.append(np.array(plan.cache_ids))
+            return plan
+
+        monkeypatch.setattr(IntentPlanner, "replan_from_queue", recording)
+        rt, res = _traced_run(
+            stream=_Repeat() if stream == "repeat" else None)
+        assert len(ids) == res.replans >= 3   # one solve call per replan
+        changed = [i == 0 or not np.array_equal(ids[i], ids[i - 1])
+                   for i in range(len(ids))]
+        views = _spans(rt, "serve.plan.probe_view")
+        built = [any(_inside(v, p) for v in views)
+                 for p in _spans(rt, "serve.plan")]
+        assert built == changed
+        skipped = rt.telemetry.counter_value("serve.refresh_skipped")
+        assert len(views) == res.replans - skipped
+        if stream == "repeat":
+            assert len(views) == 1
+        else:
+            assert len(views) == res.replans
+
+    def test_disabled_tracer_enters_no_annotation(self, monkeypatch):
+        import repro.obs.trace as trace_mod
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(trace_mod, "_annotate", Annotation)
+        off = SpanTracer(enabled=False)
+        with off.span("serve.plan"):
+            pass
+        _traced_run(rounds=8, trace=False)
+        assert entered == []
+        on = SpanTracer()
+        with on.span("serve.plan"):
+            on.point("serve.requeue")
+            on.record("serve.round", 0, 1)
+        assert entered == ["serve.plan"]    # span() only, not records
+
+    def test_profiler_capture_holds_the_phase_spans_on_its_clock(
+            self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        _traced_run(rounds=8)                  # compile outside the trace
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            rt, _ = _traced_run(rounds=16)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        host = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(int(e.start_ns))
+        for name in ("serve.plan", "serve.probe"):
+            ours = [e["t0_ns"] for e in _spans(rt, name)]
+            theirs = sorted(host.get(name, []))
+            assert len(theirs) == len(ours) >= 2, name
+            off = theirs[0] - ours[0]
+            err = np.abs(np.asarray(theirs) - np.asarray(ours) - off)
+            assert err.max() < 100_000, (name, err.max())
+
+    def test_a_compile_inside_run_is_counted_and_spanned(self):
+        import jax
+
+        rt, _ = _traced_run(rounds=8)       # the runtime's shapes, warm
+        stream = DriftingZipfStream(2048, 8, zipf_a=1.2, arrival_rate=16,
+                                    scenario="steady", seed=9)
+        forced = {}
+
+        class Forcing:
+            """Arrivals whose round 3 compiles a shape seen nowhere else."""
+
+            def arrivals(self, rnd):
+                if rnd == 3:
+                    t0 = rt.tracer.now_ns()
+                    jax.jit(lambda x: x * 3 + 1)(
+                        np.zeros((7, 5, 3), np.float32))
+                    forced["at"] = (t0, rt.tracer.now_ns())
+                return stream.arrivals(rnd)
+
+        heard = []
+
+        def listen(event, secs, **_):
+            if event in ("/jax/core/compile/backend_compile_duration",
+                         "/jax/compilation_cache/"
+                         "cache_retrieval_time_sec"):
+                heard.append(secs)
+
+        n0 = rt.telemetry.counter_value("jit.compiles")
+        s0 = len(_spans(rt, "jit.compile"))
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            rt.run(Forcing(), 8, warmup_backlog=0)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+        spans = _spans(rt, "jit.compile")[s0:]
+        assert len(heard) >= 1
+        assert rt.telemetry.counter_value("jit.compiles") - n0 \
+            == len(heard) == len(spans)
+        a, b = forced["at"]
+        assert sum(a <= e["t0_ns"] and e["t1_ns"] <= b
+                   for e in spans) == 1
+
+    def test_an_injected_tracer_does_not_turn_on_attribution(self):
+        tr = SpanTracer()
+        rt, res = _traced_run(rounds=8, trace=False, tracer=tr)
+        assert rt.tracer is tr and tr.count > 0
+        assert rt.attribution is None
+        assert len(_spans(rt, "serve.plan")) == res.replans >= 1
 
 
 class TestSharedBusThreading:
