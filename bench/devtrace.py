@@ -26,8 +26,10 @@ import numpy as np
 OPEN, CLOSE = "bench.open", "bench.close"
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
-# spans that wrap the others: a gap is named by what ran inside them
-ENVELOPES = frozenset({"serve.round", "serve.request", "bench.segment"})
+# spans that wrap the others: a gap is named by what ran inside them (a
+# replan's gap by its part: serve.plan.probe_view, serve.plan.solve, ...)
+ENVELOPES = frozenset({"serve.round", "serve.request", "bench.segment",
+                       "serve.plan"})
 NO_SPAN = "outside_program_spans"
 
 

@@ -14,6 +14,8 @@ Latency runs from the request's due time to that end.
 After the window no new request is released, and the runtime serves
 what it holds, for up to the mix's ``drain_limit_s``: every request
 released in the run has then been served, or it counts as unserved.
+A closed loop that outlasts its pool of keys recycles it, and each
+release is compared with the reference of the pool row it took.
 On more than one chip the table is built row-sharded over the cell's
 chips, as the program's mesh collective places it.
 """
@@ -246,7 +248,7 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
         offsets = np.cumsum(exponential_gaps(rate, n_open, seed))
         total = prime + n_open
     keys = request_keys(rows, traffic, total, seed)
-    reqs = Requests(keys)
+    reqs = Requests(keys, pool_from=prime)
     t_traffic = time.perf_counter_ns()
     bus = recording_bus()
     tracer = SpanTracer(capacity=SPAN_CAPACITY)
@@ -285,9 +287,7 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
               "setup_runtime_and_prime_s": (origin - t_traffic) / 1e9}
     w0, w1 = origin + warm_ns, origin + warm_ns + win_ns
     if closed:
-        stream = ClosedLoop(reqs, int(traffic["outstanding"]), bus,
-                            first=prime)
-        lo, hi = prime, total
+        stream = ClosedLoop(reqs, int(traffic["outstanding"]), bus)
     else:
         stream = OpenLoop(reqs, offsets, first=prime)
         stream.start(origin)
@@ -375,6 +375,7 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
                          cover.items(), key=lambda kv: -kv[1])[:4])}
     due_ns = reqs.due_ns[rids]
     enq_ns = reqs.enqueue_ns(rids)
+    cycles = {"pool_cycles": stream.pool_cycles} if closed else {}
     del rt, table, burst, stream
     gc.unfreeze()
     gc.collect()        # the runtime holds cycles; free the table now
@@ -389,7 +390,7 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
         if got is None:
             wrong += 1
             continue
-        want = reference_rows(keys[rid], dim, seed)
+        want = reference_rows(keys[reqs.row_of(int(rid))], dim, seed)
         d = float(np.max(np.abs(np.asarray(got, np.float32) - want)))
         gap = max(gap, d)
         wrong += d > 0
@@ -410,7 +411,8 @@ def run_cell(config: dict, traffic: dict, seed: int, seconds: float,
               * int(traffic["keys_per_request"]),
               row_bytes=dim * DTYPE_BYTES[dtype],
               spans=spans, bus_log=bus.log, peaks=peaks, trace=trace,
-              info={**phases, **backlog, **window_counts(bus.log, w0, w1),
+              info={**phases, **backlog, **cycles,
+                    **window_counts(bus.log, w0, w1),
                     "compiles_in_window": compiles.count(w0, w1),
                     **stall, **usage.report(),
                     "requests_in_window": int(rids.size),

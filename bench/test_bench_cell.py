@@ -36,8 +36,9 @@ def _cell(kind: str):
     return config, t
 
 
-def _run(kind="zipf-rate", dtype=None, seconds=1.0):
+def _run(kind="zipf-rate", dtype=None, seconds=1.0, **traffic):
     config, t = _cell(kind)
+    t.update(traffic)
     return serve.run_cell(config, t, SEED, seconds, False,
                           time.perf_counter_ns(),
                           spec.peaks("TPU v5 lite"), 1, dtype=dtype)
@@ -51,6 +52,42 @@ def test_the_program_serves_every_row_of_the_reference(kind):
     assert ctx.info["knobs_moved"] == "none"
     assert (ctx.served_ns >= ctx.due_ns).all()
     assert ctx.setup_s > 0
+
+
+POOL = 64       # a pool this small wraps many times in a 1 s CPU run
+
+
+def test_a_closed_loop_that_outlasts_its_pool_recycles_it_and_is_correct():
+    ctx, checks, attempted, failed, _ = _run("zipf-max", pool_requests=POOL)
+    assert spec.is_correct(checks), checks
+    assert ctx.info["pool_cycles"] >= 2
+    # every request served from the window's open on is compared
+    served = sum(1 for n, _, t1, _, _ in ctx.spans
+                 if n == "serve.request" and t1 >= ctx.window_ns[0])
+    assert ctx.info["requests_compared"] == served == attempted > 2 * POOL
+    assert failed == 0
+
+
+def test_a_wrong_answer_after_the_wrap_is_not_correct(monkeypatch):
+    """The program's answer altered for one pool row, in the releases
+    that recycle it only: the run is not correct."""
+    from repro.serve.runtime import ServingRuntime
+    real = ServingRuntime.run
+    prime = _cell("zipf-max")[1]["prime_requests"]
+
+    def altered(self, *a, **k):
+        res = real(self, *a, **k)
+        for rid, out in res.outputs.items():
+            if rid >= prime + POOL and (rid - prime) % POOL == 3:
+                res.outputs[rid] = out + 1.0
+        return res
+
+    monkeypatch.setattr(ServingRuntime, "run", altered)
+    ctx, checks, _, failed, _ = _run("zipf-max", pool_requests=POOL)
+    assert ctx.info["pool_cycles"] >= 2
+    assert not spec.is_correct(checks)
+    assert 0 < checks["requests_wrong"][0] == failed
+    assert checks["requests_wrong"][0] <= ctx.info["pool_cycles"]
 
 
 def test_the_control_in_bfloat16_is_not_correct():

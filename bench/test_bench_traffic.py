@@ -82,8 +82,35 @@ def test_closed_loop_keeps_its_requests_outstanding():
     bus.inc("serve.requests", 3, tenant="default")
     assert [r.rid for r in loop.arrivals(2)] == [5, 6, 7]
     bus.inc("serve.requests", 20, tenant="default")
-    with pytest.raises(RuntimeError, match="exhausted"):
-        loop.arrivals(3)
+    # past the pool's 20 rows the loop recycles it; it never runs dry
+    assert [r.rid for r in loop.arrivals(3)] == list(range(8, 28))
+    assert loop.pool_cycles == 1
+
+
+def test_a_closed_loop_recycles_its_pool_row_by_row():
+    """40 releases over a pool of 8 requests, after 2 primed ones: rids
+    unique and increasing, keys cycling through the pool's rows, a due
+    stamp for every release."""
+    from repro.obs.telemetry import Telemetry
+    bus = Telemetry()
+    prime, pool = 2, 8
+    keys = np.arange(2 * (prime + pool), dtype=np.int32).reshape(-1, 2)
+    reqs = traffic.Requests(keys, pool_from=prime)
+    loop = traffic.ClosedLoop(reqs, 4, bus)
+    got = []
+    for rnd in range(10):
+        got += loop.arrivals(rnd)
+        bus.inc("serve.requests", 4, tenant="default")
+    rids = [r.rid for r in got]
+    assert rids == list(range(prime, prime + 40))
+    rows = prime + np.arange(40) % pool
+    np.testing.assert_array_equal(np.stack([r.keys for r in got]),
+                                  keys[rows])
+    assert [reqs.row_of(i) for i in rids] == rows.tolist()
+    assert reqs.row_of(1) == 1                  # the primed rows stay
+    assert (reqs.due_ns[prime:prime + 40] > 0).all()
+    assert reqs.issued == prime + 40 and len(reqs.req) >= reqs.issued
+    assert loop.pool_cycles == 4                # wrapped after 8, .., 32
 
 
 def _ctx(due, enq, served):

@@ -3,6 +3,8 @@
 One general generator reads every mix from its data file under
 ``bench/traffic/``.  All keys and due times exist before the runtime
 sees the first request; the stream adapters only hand out what is due.
+A closed loop that outlasts its pool of keys recycles it row by row
+(`Requests.row_of`).
 
 Keys follow the truncated Zipf of the program's `SyntheticCorpus`
 (probability of rank r proportional to r**-a over a seeded permutation
@@ -58,21 +60,39 @@ def request_keys(rows: int, traffic: Dict, n: int, seed: int) -> np.ndarray:
 
 
 class Requests:
-    """Per-request record of what the harness handed the runtime.
+    """Per-release record of what the harness handed the runtime.
 
-    ``due_ns`` is when the request was due (open loop: its scheduled
-    arrival; closed loop: its release), on the `time.perf_counter_ns`
-    clock; ``req`` holds the objects the runtime stamped on enqueue."""
+    Release ``i`` is the request with rid ``i``: rids are unique and
+    increase.  It takes its keys from row `row_of(i)`: row ``i`` up to the
+    last row, then the rows from ``pool_from`` on again, in order, so a
+    closed loop that outlasts its pool recycles it.  ``due_ns`` is when the
+    release was due (open loop: its scheduled arrival; closed loop: its
+    release), on the `time.perf_counter_ns` clock; ``req`` holds the
+    objects the runtime stamped on enqueue.  Both grow by doubling, so a
+    release costs O(1) amortised."""
 
-    def __init__(self, keys: np.ndarray):
+    def __init__(self, keys: np.ndarray, pool_from: int = 0):
         self.keys = keys
+        self.pool_from = pool_from
+        self.pool = keys.shape[0] - pool_from
         self.due_ns = np.full(keys.shape[0], -1, np.int64)
         self.req: List[object] = [None] * keys.shape[0]
         self.issued = 0
 
+    def row_of(self, i: int) -> int:
+        """The row of ``keys`` that release ``i`` takes."""
+        if i < self.pool_from:
+            return i
+        return self.pool_from + (i - self.pool_from) % self.pool
+
     def make(self, i: int, due_ns: int):
         from repro.serve.requests import ServeRequest
-        r = ServeRequest(i, self.keys[i])
+        if i >= self.due_ns.size:
+            more = max(i + 1, 2 * self.due_ns.size) - self.due_ns.size
+            self.due_ns = np.concatenate(
+                [self.due_ns, np.full(more, -1, np.int64)])
+            self.req.extend([None] * more)
+        r = ServeRequest(i, self.keys[self.row_of(i)])
         self.due_ns[i] = due_ns
         self.req[i] = r
         self.issued = max(self.issued, i + 1)
@@ -117,14 +137,14 @@ class OpenLoop:
 class ClosedLoop:
     """Stream adapter keeping ``outstanding`` requests in the system:
     each one served (the runtime's ``serve.requests`` counter) releases
-    the next.  A run that outlasts the pool of keys fails."""
+    the next, from rid ``requests.pool_from`` on.  Past the pool's last
+    row the releases recycle the pool (`Requests.row_of`), so a run is
+    never cut short by the program's own speed."""
 
-    def __init__(self, requests: Requests, outstanding: int, bus,
-                 first: int = 0):
+    def __init__(self, requests: Requests, outstanding: int, bus):
         self.r = requests
         self.outstanding = outstanding
         self.bus = bus
-        self.next = first
         self.released = 0
         self.served0 = self._served()
 
@@ -132,16 +152,16 @@ class ClosedLoop:
         return int(self.bus.counter_value("serve.requests",
                                           tenant="default"))
 
+    @property
+    def pool_cycles(self) -> int:
+        """How many times the releases wrapped past the pool's last row."""
+        return max(0, self.released - 1) // self.r.pool
+
     def arrivals(self, rnd: int):
         n = self.outstanding - (self.released
                                 - (self._served() - self.served0))
         now = time.perf_counter_ns()
-        pool = self.r.keys.shape[0]
-        out = []
-        for _ in range(max(0, n)):
-            if self.next >= pool:
-                raise RuntimeError("closed-loop request pool exhausted")
-            out.append(self.r.make(self.next, now))
-            self.next += 1
+        first = self.r.pool_from + self.released
+        out = [self.r.make(i, now) for i in range(first, first + max(0, n))]
         self.released += len(out)
         return out
