@@ -92,6 +92,18 @@ def _np_scatter_set(dst, idx, val):
     return dst
 
 
+def cache_probe(xp, cache_ids, tok):
+    """Each token's slot in the sorted (C,) cache ids and whether it is
+    cached there: one binary search, for numpy and jnp alike."""
+    T = tok.shape[0]
+    C = cache_ids.shape[0]
+    if not C:
+        return xp.zeros((T,), xp.int32), xp.zeros((T,), bool)
+    cache_slot = xp.clip(xp.searchsorted(cache_ids, tok),
+                         0, C - 1).astype(xp.int32)
+    return cache_slot, cache_ids[cache_slot] == tok
+
+
 def _compact_math(xp, scatter_set, cache_ids, tok, miss_capacity: int):
     """THE probe/compact/segment arithmetic, once, for numpy and jnp.
 
@@ -107,15 +119,8 @@ def _compact_math(xp, scatter_set, cache_ids, tok, miss_capacity: int):
     slot for the static capacity to be exact (see ISSUE 2)."""
     M = miss_capacity
     T = tok.shape[0]
-    C = cache_ids.shape[0]
     int32 = xp.int32
-    if C:
-        cache_slot = xp.clip(xp.searchsorted(cache_ids, tok),
-                             0, C - 1).astype(int32)
-        hit = cache_ids[cache_slot] == tok
-    else:
-        cache_slot = xp.zeros((T,), int32)
-        hit = xp.zeros((T,), bool)
+    cache_slot, hit = cache_probe(xp, cache_ids, tok)
 
     order = xp.argsort(tok).astype(int32)        # THE step's one sort
     s = tok[order]

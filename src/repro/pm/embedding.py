@@ -48,8 +48,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
-from repro.kernels.pm_forward import (StepResidual, host_compact,
-                                      probe_and_compact, step_residual)
+from repro.kernels.pm_forward import (StepResidual, cache_probe,
+                                      host_compact, probe_and_compact,
+                                      step_residual)
 from repro.pm.collectives import resolve
 
 
@@ -348,43 +349,32 @@ def _route_overflow(hit, buf_ids, buf_slot, overflow, n_miss: int,
 
 
 class CacheProbeView:
-    """Memoized host probe for ONE cache generation (ISSUE 9 satellite).
+    """Host probe for ONE cache generation.
 
     `probe_host` re-derives the probe from scratch on every batch — one
     argsort of the batch tokens PLUS a binary search of every token
-    against the sorted cache ids — even though the cache ids only change
-    once per refresh/replan round.  This view pays one O(V) lookup-table
-    build when the cache generation changes and then probes each batch
-    with two vectorized table reads; the only per-batch sort left is the
-    `np.unique` over the batch's missed tokens, which any compaction
-    needs.  Every `HostProbe` field is byte-identical to `probe_host`
-    (pinned in tests/test_prefetch.py) — `np.unique` returns the missed
-    ids ascending with duplicates sharing one inverse slot, exactly
-    `_compact_math`'s miss-group ranks."""
+    against the sorted cache ids — and runs the full `_compact_math`
+    index stage besides.  This view keeps only the sorted (C,) cache ids
+    of the generation, so building it costs O(C) and allocates nothing
+    V-sized; each batch then pays one binary search of its tokens
+    against them and the `np.unique` over its missed tokens, which any
+    compaction needs.  Every `HostProbe` field is byte-identical to
+    `probe_host` (pinned in tests/test_prefetch.py) — the slot and hit
+    come from `_compact_math`'s own `cache_probe`, and `np.unique`
+    returns the missed ids ascending with duplicates sharing one inverse
+    slot, exactly `_compact_math`'s miss-group ranks."""
 
     def __init__(self, cache_ids: np.ndarray, vocab: int):
-        cache_ids = np.asarray(cache_ids)
-        self.cache_ids = cache_ids
+        self.cache_ids = np.asarray(cache_ids)
         self.vocab = int(vocab)
-        C = cache_ids.shape[0]
-        vals = np.arange(self.vocab, dtype=cache_ids.dtype)
-        if C:
-            slot = np.clip(np.searchsorted(cache_ids, vals),
-                           0, C - 1).astype(np.int32)
-            self._slot_lut = slot
-            self._hit_lut = cache_ids[slot] == vals
-        else:
-            self._slot_lut = np.zeros(self.vocab, np.int32)
-            self._hit_lut = np.zeros(self.vocab, bool)
 
     def probe(self, tok, miss_capacity: int, *, owner_shards: int = 0,
               route_capacity: int = 0) -> HostProbe:
-        """`probe_host(self.cache_ids, tok, ...)`, via the LUTs."""
+        """`probe_host(self.cache_ids, tok, ...)`, by one binary search."""
         tok = np.asarray(tok, dtype=np.int32)
         T = tok.shape[0]
         M = miss_capacity
-        cache_slot = self._slot_lut[tok]
-        hit = self._hit_lut[tok]
+        cache_slot, hit = cache_probe(np, self.cache_ids, tok)
         miss = ~hit
         uniq, inverse = np.unique(tok[miss], return_inverse=True)
         n_miss = int(uniq.shape[0])

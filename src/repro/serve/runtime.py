@@ -309,8 +309,8 @@ class ServingRuntime:
         self._cache_ids = None           # device copy (refresh input)
         self._cache_ids_np = None        # host copy (admission-time probe)
         self._cache_rows = None
-        # memoized probe LUTs, rebuilt once per cache generation (the
-        # per-batch probe then never re-sorts the cache side)
+        # host probe view of the current cache generation: the sorted
+        # cache ids, swapped in O(C) whenever the cache ids change
         self._probe_view: Optional[CacheProbeView] = None
         # staged prefetch (pipeline_depth >= 1): the tenure's predicted
         # miss rows, gathered once per replan/refresh instead of riding
@@ -580,8 +580,8 @@ class ServingRuntime:
         else:
             self._cache_ids_np = self.plan.cache_ids
             self._cache_ids = jnp.asarray(self.plan.cache_ids)
-            # new cache generation: rebuild the memoized probe LUTs once
-            # (the per-batch probe never re-sorts the cache side again)
+            # new cache generation: swap in its probe view (an O(C)
+            # build; each batch binary-searches the sorted cache ids)
             with tr.span("serve.plan.probe_view", a=rnd):
                 self._probe_view = CacheProbeView(self._cache_ids_np,
                                                   self.cfg.vocab)
@@ -890,8 +890,9 @@ class ServingRuntime:
                                  self.plan.miss_capacity)
                              if self._owner_shards else 0)
                 with tr.span("serve.probe", a=rnd):
-                    # memoized LUT probe — byte-identical to `probe_host`
-                    # on this cache generation (tests/test_prefetch.py)
+                    # one binary search against this generation's cache
+                    # ids — byte-identical to `probe_host` on it
+                    # (tests/test_prefetch.py)
                     probe = self._probe_view.probe(
                         batch.tokens.reshape(B * K),
                         self.plan.miss_capacity,

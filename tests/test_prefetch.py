@@ -88,6 +88,21 @@ class TestPlanAhead:
 # generation-keyed probe view (pm/embedding.py, satellite 1)
 # --------------------------------------------------------------------------
 class TestCacheProbeView:
+    @staticmethod
+    def _same(cache_ids, view, tok, m, owner_shards=0, route_capacity=0,
+              vocab=V):
+        ref = probe_host(cache_ids, tok, m, owner_shards=owner_shards,
+                         route_capacity=route_capacity, vocab=vocab)
+        got = view.probe(tok, m, owner_shards=owner_shards,
+                         route_capacity=route_capacity)
+        for f in ref._fields:
+            r, g = getattr(ref, f), getattr(got, f)
+            if isinstance(r, np.ndarray):
+                assert g.dtype == r.dtype, f
+                np.testing.assert_array_equal(g, r, err_msg=f)
+            else:
+                assert g == r, f
+
     def _check(self, owner_shards=0, route_capacity=0, cap=C, seed=0):
         rng = np.random.default_rng(seed)
         cache_ids = np.sort(rng.choice(V, size=cap, replace=False)) \
@@ -96,18 +111,8 @@ class TestCacheProbeView:
         for _ in range(10):
             tok = rng.integers(0, V, size=32)
             for m in (4, 8, 16):
-                ref = probe_host(cache_ids, tok, m,
-                                 owner_shards=owner_shards,
-                                 route_capacity=route_capacity, vocab=V)
-                got = view.probe(tok, m, owner_shards=owner_shards,
-                                 route_capacity=route_capacity)
-                for f in ref._fields:
-                    r, g = getattr(ref, f), getattr(got, f)
-                    if isinstance(r, np.ndarray):
-                        assert g.dtype == r.dtype, f
-                        np.testing.assert_array_equal(g, r, err_msg=f)
-                    else:
-                        assert g == r, f
+                self._same(cache_ids, view, tok, m, owner_shards,
+                           route_capacity)
 
     def test_matches_probe_host(self):
         self._check()
@@ -117,6 +122,49 @@ class TestCacheProbeView:
 
     def test_empty_cache(self):
         self._check(cap=0, seed=2)
+
+    @pytest.mark.parametrize("case", ["planner_padded", "edge_tokens"])
+    def test_matches_probe_host_cases(self, case):
+        """The planner's cache shape (sorted int32, its unfilled tail
+        padded with V) and tokens at the edges of the id space and of
+        the cache: 0, V-1, below the smallest cache id, above the
+        largest real one."""
+        rng = np.random.default_rng(4)
+        real = np.sort(rng.choice(np.arange(2, V - 2), size=C - 5,
+                                  replace=False))
+        cache_ids = np.full(C, V, np.int32)
+        cache_ids[:C - 5] = real
+        view = CacheProbeView(cache_ids, V)
+        lo, hi = int(real[0]), int(real[-1])
+        for _ in range(10):
+            tok = rng.integers(0, V, size=32)
+            if case == "edge_tokens":
+                tok[:8] = [0, V - 1, lo - 1, lo, hi, hi + 1, 0, V - 1]
+                rng.shuffle(tok)
+            for m in (4, 8, 16):
+                self._same(cache_ids, view, tok, m)
+                self._same(cache_ids, view, tok, m, owner_shards=8,
+                           route_capacity=2)
+
+    def test_build_allocates_nothing_vocab_sized(self):
+        """A cache generation's view costs O(C): at V = 10 M and C =
+        8,192 the build's traced peak stays under 1 MiB (tables over V
+        would take tens of MB), and a batch still probes exactly."""
+        import tracemalloc
+        big_v, cap = 10_000_000, 8192
+        rng = np.random.default_rng(5)
+        cache_ids = np.sort(rng.choice(big_v, size=cap, replace=False)
+                            ).astype(np.int32)
+        tracemalloc.start()
+        try:
+            view = CacheProbeView(cache_ids, big_v)
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        assert peak < 1 << 20, peak
+        tok = np.concatenate([rng.choice(cache_ids, size=800),
+                              rng.integers(0, big_v, size=800)])
+        self._same(cache_ids, view, tok, 1024, vocab=big_v)
 
 
 # --------------------------------------------------------------------------
