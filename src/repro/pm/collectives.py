@@ -66,10 +66,12 @@ recompilation churn.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops, ref
@@ -88,6 +90,20 @@ def route_block_cap(m: int, n: int) -> int:
     while p < c:
         p *= 2
     return min(m, p)
+
+
+def route_falls_back(ids, vocab: int, n: int) -> bool:
+    """Host mirror of the `lax.cond` in `MeshBackend.gather_rows_routed`
+    at its derived block: True when the real ids (``< vocab``) of the
+    host buffer ``ids`` crowd one of the ``n`` owners past
+    `route_block_cap(len(ids), n)`, so the gather takes the psum arm."""
+    ids = np.asarray(ids)
+    cap = route_block_cap(ids.shape[0], n)
+    if cap >= ids.shape[0]:
+        return False
+    owner = ids[ids < vocab].astype(np.int64) // (vocab // n)
+    return owner.size > cap and \
+        int(np.bincount(owner, minlength=n).max()) > cap
 
 
 def _all_to_all_route(axis: str, n: int, block: int, vocab: int,
@@ -308,10 +324,12 @@ class MeshBackend:
         partials: per-device comm ``n * cap * D ~ 2·M·D``, independent of
         n_shards.
 
-        ``route_cap`` pins the static per-owner block (the serving plan's
-        `route_capacity`); 0 derives `route_block_cap(M, n)`.  A batch
-        whose worst per-owner count exceeds the cap falls back to the
-        replicated psum under one `lax.cond` — correct, just slower."""
+        ``route_cap`` pins the static per-owner block; 0 (every caller in
+        the program) derives `route_block_cap(M, n)` from the buffer's
+        length, so the block adds no shape of its own.  A batch whose
+        worst per-owner count exceeds the cap falls back to the
+        replicated psum under one `lax.cond` — correct, just slower
+        (`route_falls_back` decides the same on the host)."""
         V, D = table.shape
         block = self._check(V)
         n = self.n_shards
@@ -485,10 +503,10 @@ class MeshBackend:
         ascending with V-pads (the cache contract), exactly the layout the
         router wants, and `searchsorted` recovers the real-id count
         without a sort.  Pad ids >= V belong to no shard and come back
-        zero — the padded-cache contract."""
-        ids = cache_ids.astype(jnp.int32)
-        n_valid = jnp.searchsorted(ids, jnp.int32(table.shape[0]))
-        return self.gather_rows_routed(table, ids, n_valid)
+        zero — the padded-cache contract.  Jitted (`_routed_refresh`): an
+        eager call would trace and compile its `shard_map` and `lax.cond`
+        anew on every refresh."""
+        return _routed_refresh(self, table, cache_ids)
 
     def refresh_rows_delta(self, table, cache_rows, ids, slots):
         """Incremental replica sync through the routed owner-block
@@ -496,10 +514,17 @@ class MeshBackend:
         wants) cross the mesh; everything else in ``cache_rows`` is
         bitwise current already (delta-refresh gate, `train/loop.py`).
         Pad slots == C drop off the end of the cache buffer."""
-        ids = ids.astype(jnp.int32)
-        n_valid = jnp.searchsorted(ids, jnp.int32(table.shape[0]))
-        rows = self.gather_rows_routed(table, ids, n_valid)
+        rows = _routed_refresh(self, table, ids)
         return cache_rows.at[slots.astype(jnp.int32)].set(rows, mode="drop")
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _routed_refresh(backend: MeshBackend, table, ids):
+    """`MeshBackend.gather_rows_routed` over ascending V-padded ``ids``,
+    compiled once per backend and shape."""
+    ids = ids.astype(jnp.int32)
+    n_valid = jnp.searchsorted(ids, jnp.int32(table.shape[0]))
+    return backend.gather_rows_routed(table, ids, n_valid)
 
 
 #: module-level default: the training path's single-device reference.

@@ -33,7 +33,7 @@ tiles to runtime parameters.  Two mechanisms, by information source:
 Every knob value lives on a bucketed ladder (powers of two for capacity),
 so downstream jitted executables specialize per bucket and revisiting a
 bucket never recompiles — the exact discipline of
-`serve.runtime._managed_fn(route_cap)` and the train loop's
+`serve.runtime._managed_fn` and the train loop's
 miss-capacity `step_fns`.
 
 Decisions and their causes are published to the telemetry bus

@@ -84,7 +84,7 @@ def refresh_cache(state: EmbedPMState, cache_ids: jnp.ndarray | None = None,
 
 def combine_miss_buffer(backend, table, cache_rows, hit, cache_slot,
                         buf_ids, buf_slot, *, kernel: bool = False,
-                        n_miss=None, route_cap: int = 0):
+                        n_miss=None):
     """THE shared managed-lookup data path (all variants funnel here):
     move the compact unique-miss buffer through the backend's
     vocab-parallel collective, append the all-zero trash row (slot M —
@@ -94,13 +94,13 @@ def combine_miss_buffer(backend, table, cache_rows, hit, cache_slot,
     ``n_miss`` (the probe's unique-miss count) switches the mesh backend
     onto the destination-compacted routed gather (DESIGN.md §12): only
     each owner's run of the compact ids moves, instead of the full
-    replicated buffer riding a psum.  ``route_cap`` optionally pins the
-    routed per-owner block (the serving plan's `route_capacity`)."""
+    replicated buffer riding a psum; its per-owner block derives from
+    the buffer's length (`route_block_cap`)."""
     be = resolve(backend)
     if getattr(be, "mesh_real", False) and n_miss is not None:
         buf_rows = be.gather_rows_routed(
             table, buf_ids, jnp.minimum(n_miss, buf_ids.shape[0]),
-            route_cap=route_cap, kernel=kernel)
+            kernel=kernel)
     else:
         buf_rows = be.gather_rows(table, buf_ids, kernel=kernel)
     buffer = jnp.concatenate(
@@ -396,7 +396,7 @@ class CacheProbeView:
 def planned_serve_lookup(table, cache_rows, buf_ids, hit, cache_slot,
                          buf_slot, *, n_shards: int = 1,
                          kernel: bool = False, backend=None,
-                         n_miss=None, route_cap: int = 0):
+                         n_miss=None):
     """Device data path of the serving lookup, with the index stage
     already done (`probe_host` at admission — intent means the host knows
     the batch's miss set before the batch runs).  Only the (M+1, D)
@@ -407,13 +407,13 @@ def planned_serve_lookup(table, cache_rows, buf_ids, hit, cache_slot,
 
     ``n_miss`` (host probe's unique-miss count, passed as a device
     scalar) routes the mesh backend onto the destination-compacted gather
-    with per-owner blocks of ``route_cap`` (the plan's `route_capacity`;
-    the runtime's per-owner admission guarantees the cap fits, and the
-    psum fallback arm keeps even an unplanned batch correct)."""
+    with per-owner blocks of `route_block_cap` (M, n_shards): one program
+    per buffer shape.  A batch whose misses crowd one owner past that
+    block takes the psum fallback arm, still exact (the runtime counts
+    it as ``serve.route_fallbacks``)."""
     return combine_miss_buffer(resolve(backend, n_shards), table,
                                cache_rows, hit, cache_slot, buf_ids,
-                               buf_slot, kernel=kernel, n_miss=n_miss,
-                               route_cap=route_cap)
+                               buf_slot, kernel=kernel, n_miss=n_miss)
 
 
 # The staged serving dispatch needs no dedicated device fn: the runtime

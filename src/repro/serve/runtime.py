@@ -82,7 +82,7 @@ from repro.core.engine import StreamingIntentBuffer
 from repro.obs.attribution import PlanAttribution
 from repro.obs.telemetry import Telemetry
 from repro.obs.trace import SpanTracer, make_tracer, watch_compiles
-from repro.pm.collectives import resolve
+from repro.pm.collectives import resolve, route_falls_back
 from repro.pm.controller import (AUTO, Knob, OnlineController, capacity_ladder,
                                  is_auto, overlap_pays, pow2_ladder,
                                  resolve_knob)
@@ -281,10 +281,11 @@ class ServingRuntime:
         # mesh collective: admission is additionally bounded PER OWNER
         # SHARD — the planner publishes `route_capacity` (the exact
         # per-(step,owner) unique-miss bound over the queued horizon) and
-        # the device lookup routes per-owner blocks of exactly that size
-        # (DESIGN.md §12), so what admission admits is what the routed
-        # collective can carry.  (The per-shard bound lives on owner
-        # shards, not on the signaling nodes below.)
+        # the probe flags what would exceed it; the device lookup's own
+        # per-owner block derives from its buffer's length, and a batch
+        # past that block takes the psum arm (DESIGN.md §12).  (The
+        # per-shard bound lives on owner shards, not on the signaling
+        # nodes below.)
         self._owner_shards = (self.backend.n_shards
                               if self.backend is not None
                               and self.backend.mesh_real else 0)
@@ -332,35 +333,24 @@ class ServingRuntime:
         self._plain_fn = jax.jit(lambda t, toks: plain_serve_lookup(
             t, toks, n_shards=cfg.n_shards, backend=self.backend))
         # one jitted data-path fn; XLA re-specializes per miss bucket
-        # (buf_ids shape), per capacity bucket (cache_rows shape) and —
-        # on the mesh — per route-capacity bucket: all three ride
-        # power-of-two ladders, so a handful of executables, and
-        # revisiting a bucket never recompiles (tested).  ``nm`` (the
-        # host probe's unique-miss count) rides along as a device scalar;
-        # the non-mesh path ignores it.
-        self._managed_fns: Dict[int, callable] = {}
+        # (buf_ids shape) and per capacity bucket (cache_rows shape): both
+        # ride power-of-two ladders, so a handful of executables, and
+        # revisiting a bucket never recompiles (tested).  On the mesh the
+        # routed per-owner block derives from the buffer's length
+        # (`route_block_cap`), so the mesh compiles one program per shape,
+        # as one chip does.  ``nm`` (the host probe's unique-miss count)
+        # rides along as a device scalar; the non-mesh path ignores it.
+        self._managed_fn = jax.jit(
+            lambda t, cr, bi, h, cs, bs, nm: planned_serve_lookup(
+                t, cr, bi, h, cs, bs, n_shards=cfg.n_shards,
+                kernel=cfg.kernel, backend=self.backend,
+                n_miss=(nm if self._owner_shards else None)))
         self.overlap_ratio: Optional[float] = None
         self._calibrated = False
         self._summary_printed = False
         # controller reward epochs: measured between replan boundaries
         self._epoch_t0: Optional[float] = None
         self._epoch_served0 = 0
-
-    def _managed_fn(self, route_cap: int = 0):
-        """Jitted serving data path, specialized per routed block size
-        (0 on non-mesh backends — the router is off without ``n_miss``
-        anyway, see `planned_serve_lookup`)."""
-        cfg = self.cfg
-        fn = self._managed_fns.get(route_cap)
-        if fn is None:
-            fn = jax.jit(
-                lambda t, cr, bi, h, cs, bs, nm: planned_serve_lookup(
-                    t, cr, bi, h, cs, bs, n_shards=cfg.n_shards,
-                    kernel=cfg.kernel, backend=self.backend,
-                    n_miss=(nm if self._owner_shards else None),
-                    route_cap=route_cap))
-            self._managed_fns[route_cap] = fn
-        return fn
 
     @property
     def double_buffer(self) -> bool:
@@ -417,7 +407,7 @@ class ServingRuntime:
             def device(p):
                 idx = jnp.asarray(np.stack([p.hit.astype(np.int32),
                                             p.cache_slot, p.buf_slot]))
-                jax.block_until_ready(self._managed_fn()(
+                jax.block_until_ready(self._managed_fn(
                     self.table, cache_rows, jnp.asarray(p.buf_ids),
                     idx[0], idx[1], idx[2], jnp.int32(p.n_miss)))
 
@@ -675,6 +665,7 @@ class ServingRuntime:
         if old is None or new_ids.size == staged.size:
             self._staging_rows = resolve(self.backend).refresh_rows(
                 self.table, jnp.asarray(ids_p))
+            self._note_fallbacks([ids_p])
         else:
             # merge: one local gather of the new rows + one take over the
             # concatenated (old ++ new ++ zero) source — pads read the
@@ -684,6 +675,7 @@ class ServingRuntime:
             nids_p[:new_ids.size] = new_ids
             new_rows = resolve(self.backend).refresh_rows(
                 self.table, jnp.asarray(nids_p))
+            self._note_fallbacks([nids_p])
             # offsets index the DEVICE concat: the old buffer's padded
             # row count, not the real staged-id count
             off = int(self._staging_rows.shape[0])
@@ -731,6 +723,34 @@ class ServingRuntime:
         self.telemetry.inc("serve.stage_topups")
         self.telemetry.inc("serve.stage_topup_rows", int(new_ids.size))
 
+    def _note_fallbacks(self, gathers) -> None:
+        """Mesh only: one ``serve.route_fallbacks`` count of the routed
+        gathers over the host id buffers ``gathers`` (V-padded; None: no
+        gather) that take the psum arm, decided before dispatch
+        (`route_falls_back`)."""
+        if self._owner_shards:
+            self.telemetry.inc("serve.route_fallbacks", sum(
+                route_falls_back(ids, self.cfg.vocab, self._owner_shards)
+                for ids in gathers if ids is not None))
+
+    def _note_mesh_batch(self, probe, staged_split, n_tok: int) -> None:
+        """The mesh's own decisions for one batch, from host arrays:
+        ``serve.route_overflow_tokens``, the tokens of its requests that
+        only the per-owner bound flagged (the global bound sends its
+        overflow to the trash slot M; the per-owner bound leaves the
+        token its slot), and the routed gather's fallback."""
+        M = probe.buf_ids.shape[0]
+        self.telemetry.inc("serve.route_overflow_tokens", int(
+            np.count_nonzero(probe.overflow[:n_tok]
+                             & (probe.buf_slot[:n_tok] < M))))
+        if staged_split is not None:
+            self._note_fallbacks([staged_split[0]])
+        else:
+            # the probe pads its buffer with id 0: mark the pads as such
+            ids = probe.buf_ids.copy()
+            ids[min(probe.n_miss, M):] = self.cfg.vocab
+            self._note_fallbacks([ids])
+
     def _refresh(self, res: ServeResult, rnd: int) -> None:
         """Re-gather the replica cache (and the staging buffer) from the
         table.  Its ``serve.refresh`` span times the host's dispatch
@@ -752,6 +772,7 @@ class ServingRuntime:
                     self.table, self._staged_ids_dev)
                 self._cache_ext = jnp.concatenate([self._cache_rows,
                                                    self._staging_rows])
+            self._note_fallbacks([self._cache_ids_np, self._staged_ids])
         res.refreshes += 1
         self.telemetry.inc("serve.refreshes")
 
@@ -948,6 +969,9 @@ class ServingRuntime:
                     # moment recurring intent shows up
                     nm = min(probe.n_miss, probe.buf_ids.shape[0])
                     self._note_residual(probe.buf_ids[:nm])
+                if self._owner_shards:
+                    self._note_mesh_batch(probe, staged_split,
+                                          len(batch.reqs) * K)
                 with tr.span("serve.dispatch", a=rnd):
                     # one packed H2D transfer for the three (T,) index
                     # arrays
@@ -960,7 +984,7 @@ class ServingRuntime:
                                      ext_lut[probe.buf_slot],
                                      probe.cache_slot),
                             res_lut[probe.buf_slot]]))
-                        out = self._managed_fn(route_cap)(
+                        out = self._managed_fn(
                             self.table, self._cache_ext,
                             jnp.asarray(res_ids), idx[0], idx[1],
                             idx[2], jnp.int32(n_res))
@@ -968,7 +992,7 @@ class ServingRuntime:
                         idx = jnp.asarray(np.stack([
                             probe.hit.astype(np.int32), probe.cache_slot,
                             probe.buf_slot]))
-                        out = self._managed_fn(route_cap)(
+                        out = self._managed_fn(
                             self.table, self._cache_rows,
                             jnp.asarray(probe.buf_ids), idx[0], idx[1],
                             idx[2], jnp.int32(probe.n_miss))

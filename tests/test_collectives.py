@@ -600,3 +600,86 @@ class TestMeshServingRuntime:
         for rid, rows in res.outputs.items():
             np.testing.assert_allclose(rows, table[rid_to_keys[rid]],
                                        rtol=1e-6)
+
+
+class TestMeshServingCounters:
+    """The mesh's own decisions are counted on the host, per batch and
+    per refresh: tokens only the per-owner bound flagged
+    (``serve.route_overflow_tokens``) and routed gathers that take the
+    psum arm (``serve.route_fallbacks``); the one-chip path writes
+    neither."""
+
+    VOCAB = 256                          # four owners of 64 rows
+
+    def _runtime(self, table, collective="mesh", **kw):
+        from repro.serve import ServeConfig, ServingRuntime
+        knobs = dict(batch_requests=4, keys_per_request=8,
+                     cache_capacity=8, replan_every=1, pipeline_depth=0,
+                     refresh_every=0, summary=False)
+        knobs.update(kw)
+        if collective == "mesh":
+            knobs.update(collective="mesh", model_shards=4)
+        return ServingRuntime(table, ServeConfig(vocab=self.VOCAB, **knobs))
+
+    @staticmethod
+    def _stream(keys):
+        from repro.serve import ReplayStream
+        from repro.serve.requests import ServeRequest
+        return ReplayStream([[ServeRequest(i, np.asarray(k, np.int32))
+                              for i, k in enumerate(keys)]])
+
+    @needs(4)
+    def test_a_batch_crowding_one_owner_counts_and_is_served_exactly(
+            self, monkeypatch):
+        """Four requests of eight distinct keys, all owned by shard 0: the
+        cache (8 rows) holds ids 0-7, so ids 8-31 miss and ids 24-31 rank
+        16-23 within their owner, past a per-owner bound of 16 (the
+        global bound, 64, flags nothing).  Request 3 is requeued, then
+        served alone from a replanned cache, every row exact."""
+        from repro.pm.planner import IntentPlanner
+        monkeypatch.setattr(IntentPlanner, "_route_capacity",
+                            lambda self, keys, steps, hot: 16)
+        table = np.random.default_rng(0).normal(
+            size=(self.VOCAB, 8)).astype(np.float32)
+        keys = [np.arange(8 * i, 8 * i + 8) for i in range(4)]
+        rt = self._runtime(table)
+        res = rt.run(self._stream(keys), rounds=4, warmup_backlog=1,
+                     collect_outputs=True)
+        t = rt.telemetry
+        assert t.counter_value("serve.route_overflow_tokens") == 8
+        assert res.requeues == 1 and res.zero_served == 0
+        assert sorted(res.outputs) == [0, 1, 2, 3]
+        for rid, rows in res.outputs.items():
+            np.testing.assert_array_equal(rows, table[keys[rid]])
+
+    @needs(4)
+    def test_a_refresh_crowding_one_owner_counts_one_fallback(self):
+        """A cache generation of 64 ids, all owned by shard 0, is twice
+        the routed block of a 64-slot refresh (`route_block_cap(64, 4)`
+        = 32): the refresh takes the psum arm, counted once, and its rows
+        are the table's."""
+        table = np.random.default_rng(1).normal(
+            size=(self.VOCAB, 8)).astype(np.float32)
+        keys = [np.arange(16 * i, 16 * i + 16) for i in range(4)]
+        rt = self._runtime(table, keys_per_request=16, cache_capacity=64)
+        res = rt.run(self._stream(keys), rounds=2, warmup_backlog=1,
+                     collect_outputs=True)
+        assert res.refreshes == 1
+        assert rt.telemetry.counter_value("serve.route_fallbacks") == 1
+        np.testing.assert_array_equal(rt._cache_ids_np, np.arange(64))
+        np.testing.assert_array_equal(np.asarray(rt._cache_rows),
+                                      table[:64])
+        for rid, rows in res.outputs.items():
+            np.testing.assert_array_equal(rows, table[keys[rid]])
+
+    def test_the_one_chip_path_writes_neither_counter(self):
+        table = np.random.default_rng(2).normal(
+            size=(self.VOCAB, 8)).astype(np.float32)
+        keys = [np.arange(8 * i, 8 * i + 8) for i in range(4)]
+        rt = self._runtime(table, collective="emulated")
+        res = rt.run(self._stream(keys), rounds=4, warmup_backlog=1,
+                     collect_outputs=True)
+        assert res.served == 4
+        counters = rt.telemetry.snapshot()["counters"]
+        assert "serve.route_overflow_tokens" not in counters
+        assert "serve.route_fallbacks" not in counters

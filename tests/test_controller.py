@@ -282,9 +282,9 @@ class TestMidRunCapacityResize:
                     err_msg=f"segment {si} cap={cap} rid={rid}")
             assert len(rt.queue) == 0    # drained: segments independent
             if si == len(plan_pass) - 1:
-                sizes_after_first_pass = rt._managed_fn(0)._cache_size()
+                sizes_after_first_pass = rt._managed_fn._cache_size()
         # repeat pass saw only already-compiled buckets
-        assert rt._managed_fn(0)._cache_size() == sizes_after_first_pass
+        assert rt._managed_fn._cache_size() == sizes_after_first_pass
         assert rt.telemetry.counter_value("serve.capacity_resizes") \
             == len(segments) - 1
 

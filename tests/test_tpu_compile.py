@@ -153,8 +153,7 @@ def test_managed_lookup_compiles_on_a_four_chip_mesh(topo, monkeypatch):
     rep = NamedSharding(mesh, P())
     backend = MeshBackend(mesh)
     fn = jax.jit(lambda t, cr, bi, h, cs, bs, nm: planned_serve_lookup(
-        t, cr, bi, h, cs, bs, kernel=True, backend=backend, n_miss=nm,
-        route_cap=M // 8))
+        t, cr, bi, h, cs, bs, kernel=True, backend=backend, n_miss=nm))
     compiled = _compiled(
         fn, _spec((V, D), jnp.float32, rows),
         _spec((C, D), jnp.float32, rep), _spec((M,), jnp.int32, rep),
